@@ -113,6 +113,9 @@ def main() -> None:
         if "xla_force_host_platform_device_count" not in existing:
             os.environ["XLA_FLAGS"] = f"{existing} {flag}".strip()
 
+    from repro.common.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     rows = []
     print("name,us_per_call,derived")
 

@@ -15,11 +15,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.common.compat import tree_flatten_with_path
-
 
 def _flatten(tree):
-    flat, treedef = tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     keys = ["/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
             for path, _ in flat]
     vals = [v for _, v in flat]

@@ -1,6 +1,4 @@
-"""Shared low-level utilities: pytrees, dtypes, sharding rules, registry,
-JAX version-compat shims."""
-from repro.common.compat import shard_map
+"""Shared low-level utilities: pytrees, dtypes, sharding rules, registry."""
 from repro.common.pytree import (
     tree_add,
     tree_sub,
@@ -27,5 +25,4 @@ __all__ = [
     "param_bytes",
     "tree_any_nan",
     "Registry",
-    "shard_map",
 ]

@@ -18,7 +18,7 @@ import dataclasses
 from typing import Dict, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 MeshAxes = Union[None, str, Tuple[str, ...]]
 
@@ -252,7 +252,11 @@ def data_mesh(min_devices: int = 2) -> Optional[Mesh]:
     devices = jax.devices()
     if len(devices) < min_devices:
         return None
-    return jax.make_mesh((len(devices),), ("data",))
+    # Auto axes: the engine places arrays and lets the compiler propagate
+    # shardings (JAX's default, Explicit axes, would demand an
+    # out_sharding on every gather from the sharded client stack)
+    return jax.make_mesh((len(devices),), ("data",),
+                         axis_types=(AxisType.Auto,))
 
 
 def client_sharding(shape: Sequence[int], mesh: Optional[Mesh],

@@ -4,8 +4,9 @@
   three real-world streaming datasets (FitRec, Air Quality, ExtraSensory).
 * ``paper-cnn``: two conv layers + max-pool + FC — used for Fashion-MNIST.
 
-These run in fp32 on CPU and are the substrate for the Table 5.1 / 6.1 /
-Fig 3-6 reproduction benchmarks.
+These run in fp32 — on CPU in the tests, on a TPU through
+``chip_smoke.py`` (paper-lstm at hidden 64) — and are the substrate for the
+Table 5.1 / 6.1 / Fig 3-6 reproduction benchmarks.
 """
 from repro.configs.base import ARCHS, ModelConfig
 

@@ -77,8 +77,6 @@ class FedAsyncStrategy(Strategy):
     def build_fold_affine(self, model, cfg_model, cfg):
         # the fold is exactly affine in the server weights:
         # w_s = (1 - a_s) w_{s-1} + a_s wk_s, so a = 1 - a_t, b = a_t wk.
-        # For a single-fold tick the prefix scan evaluates the identical
-        # mul/mul/add sequence — bitwise equal to the sequential step.
         def carrier(server):
             return server["w"]
 

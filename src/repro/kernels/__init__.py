@@ -10,6 +10,9 @@ Kernels here (hot spots of the ASO-Fed system):
   flash_attention   — blocked online-softmax attention (causal/SWA/local, GQA)
   linear_scan       — chunked linear recurrence (Mamba-1 / RG-LRU)
 
-Kernels are validated on CPU with interpret=True; on TPU the same code
-compiles to Mosaic.
+Numerics are checked against the refs on CPU with interpret=True
+(tests/test_kernels.py).  On a TPU the same code compiles to Mosaic:
+tests/test_tpu_compile.py compiles each kernel for a described v5e, and
+chip_smoke.py runs feature_attention and linear_scan compiled inside the
+cohort engine on the chip.
 """

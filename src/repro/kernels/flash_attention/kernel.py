@@ -46,8 +46,8 @@ def _fa_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, o_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (blk_q, blk_k)
-        qp = qp_ref[0][:, None]  # (blk_q, 1)
-        kp = kp_ref[0][None, :]  # (1, blk_k)
+        qp = qp_ref[0]  # (blk_q, 1)
+        kp = kp_ref[0]  # (1, blk_k)
         mask = jnp.ones_like(s, dtype=jnp.bool_)
         if causal:
             mask &= kp <= qp
@@ -116,8 +116,10 @@ def flash_attention_kernel(q, k, v, q_positions, k_positions, *, causal,
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, blk_q), lambda b, h, qi, ki: (b, qi)),
-            pl.BlockSpec((1, blk_k), lambda b, h, qi, ki: (b, ki)),
+            # positions as (B, Sq, 1) and (B, 1, Skv): a block's last two
+            # dims are then whole or tiled, as the (8, 128) rule asks
+            pl.BlockSpec((1, blk_q, 1), lambda b, h, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, 1, blk_k), lambda b, h, qi, ki: (b, 0, ki)),
             pl.BlockSpec((1, 1, blk_q, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
             pl.BlockSpec(
                 (1, 1, blk_k, hd), lambda b, h, qi, ki: (b, h // G, ki, 0)
@@ -136,4 +138,4 @@ def flash_attention_kernel(q, k, v, q_positions, k_positions, *, causal,
             pltpu.VMEM((blk_q, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q_positions, k_positions, q, k, v)
+    )(q_positions[:, :, None], k_positions[:, None, :], q, k, v)

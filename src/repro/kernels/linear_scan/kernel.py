@@ -46,11 +46,13 @@ def _scan_kernel(a_ref, b_ref, h_ref, hlast_ref, carry_ref, *, chunk):
     h0 = carry_ref[...]  # (1, C)
     h_all = b + a * h0  # broadcast over chunk rows
     h_ref[0] = h_all.astype(h_ref.dtype)
+    # static row slices: an integer index lowers to a dynamic_slice,
+    # which Mosaic cannot lower
     carry_ref[...] = h_all[-1:, :]
 
     @pl.when(ci == nc - 1)
     def _last():
-        hlast_ref[0] = h_all[-1].astype(hlast_ref.dtype)
+        hlast_ref[0] = h_all[-1:, :].astype(hlast_ref.dtype)
 
 
 def linear_scan_kernel(a, b, *, chunk: int = 256, interpret: bool = False):
@@ -70,13 +72,15 @@ def linear_scan_kernel(a, b, *, chunk: int = 256, interpret: bool = False):
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, C), lambda bi, ci: (bi, ci, 0)),
-            pl.BlockSpec((1, C), lambda bi, ci: (bi, 0)),
+            # (B, 1, C) keeps the block's last two dims whole, as the
+            # (8, 128) tiling rule asks for any B
+            pl.BlockSpec((1, 1, C), lambda bi, ci: (bi, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, S, C), b.dtype),
-            jax.ShapeDtypeStruct((B, C), b.dtype),
+            jax.ShapeDtypeStruct((B, 1, C), b.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((1, C), jnp.float32)],
         interpret=interpret,
     )(a, b)
-    return h, hlast
+    return h, hlast[:, 0]

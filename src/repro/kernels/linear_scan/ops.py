@@ -87,8 +87,8 @@ def fold_prefix(a, b, h0=None, *, use_kernel: Optional[bool] = None,
     ``(S, ...)`` leaves; ``h0``: pytree matching ``b`` without the leading
     axis (None = zeros).  Returns the pytree ``h`` of ``(S, ...)`` states
     with ``h_s = a_s * h_{s-1} + b_s`` seeded at ``h0`` — the result the
-    sequential fold scan would produce, up to fp reassociation (exact for
-    S == 1, where no reassociation happens).
+    sequential fold scan would produce, up to fp reassociation and the
+    backend's choice of fused multiply-adds.
 
     Internally ``h_s = A_s * h0 + B_s`` with ``A = cumprod(a)`` and ``B``
     the zero-seeded prefix: per-leaf, large streams ride the Pallas

@@ -11,9 +11,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional
 
+import jax
 import numpy as np
 
-from repro.common.compat import tree_flatten_with_path
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models.model import build_spec
@@ -58,7 +58,7 @@ def param_counts(cfg: ModelConfig) -> Dict[str, float]:
     spec = build_spec(cfg)
     total = 0
     routed = 0
-    for path, d in tree_flatten_with_path(spec, is_leaf=is_def)[0]:
+    for path, d in jax.tree.flatten_with_path(spec, is_leaf=is_def)[0]:
         n = int(np.prod(d.shape))
         total += n
         keys = [str(getattr(p, "key", "")) for p in path]

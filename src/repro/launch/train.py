@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import save_checkpoint
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.core.feature_learning import apply_feature_learning
 from repro.data.lm import batches_from_tokens, federated_token_clients
@@ -47,6 +48,7 @@ def main():
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -25,8 +25,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from repro.common.compat import shard_map
 
 from repro.configs.base import ModelConfig
 from repro.models.dist import DistContext

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.common.compat import tree_flatten_with_path
-
 from repro.common.sharding import ShardingRules
 
 
@@ -107,7 +105,7 @@ def spec_param_count(spec) -> int:
 
 def validate_divisibility(spec, rules: ShardingRules, mesh) -> None:
     """Raise early if any parameter can't be laid out on the mesh."""
-    for path, d in tree_flatten_with_path(spec, is_leaf=is_def)[0]:
+    for path, d in jax.tree.flatten_with_path(spec, is_leaf=is_def)[0]:
         try:
             rules.check_divisible(d.shape, d.axes, mesh)
         except ValueError as e:

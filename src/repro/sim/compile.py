@@ -29,10 +29,10 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.common import sharding as sharding_lib
-from repro.common.compat import shard_map
 from repro.common.pytree import tree_take, tree_scatter, tree_where
 from repro.kernels.linear_scan import ops as scan_ops
 
@@ -309,7 +309,12 @@ def tick_body(strategy, model, cfg_model, cfg, mesh: Optional[Mesh], codec,
             admit = mask
         tel_row = reduce_telemetry(tel, mask, slots)
         if fold is not None:
-            if affine is not None:
+            # a one-slot bucket has no prefix to parallelize: it runs the
+            # sequential step itself, so fold depth 1 is bitwise in every
+            # mode (the affine form's final add reaches the backend with
+            # its operands swapped, and XLA:CPU contracts the first
+            # product into an FMA: 1 ulp apart)
+            if affine is not None and idx.shape[0] > 1:
                 # parallel fast path: the tick's folds as one log-depth
                 # affine prefix scan over the coefficient stream (masked
                 # AND rejected slots are identity by the coeffs contract
@@ -329,10 +334,19 @@ def tick_body(strategy, model, cfg_model, cfg, mesh: Optional[Mesh], codec,
                             a_s.shape + (1,) * (b.ndim - 1)) * b + b, b_s)
                     b_s = mask_select(dd, b2, b_s)
                     a_s = jnp.where(dd, a_s * a_s, a_s)
-                h = scan_ops.fold_prefix(
-                    a_s, b_s, carrier(server),
-                    use_kernel=cfg.fold_kernel,
-                    interpret=cfg.fold_kernel_interpret)
+                def prefix(a, b, h0):
+                    return scan_ops.fold_prefix(
+                        a, b, h0, use_kernel=cfg.fold_kernel,
+                        interpret=cfg.fold_kernel_interpret)
+
+                if mesh is not None:
+                    # the fold runs replicated; a Mosaic kernel cannot be
+                    # partitioned by the compiler, so every device runs
+                    # it whole inside an explicit SPMD region
+                    prefix = shard_map(prefix, mesh=mesh,
+                                       in_specs=(P(), P(), P()),
+                                       out_specs=P(), check_vma=False)
+                h = prefix(a_s, b_s, carrier(server))
                 server, received = unfold(server, h, aux, uploads, idx,
                                           n_vis, t_arr, admit)
             else:

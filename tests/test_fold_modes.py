@@ -5,8 +5,8 @@ Contracts pinned here:
 * ``fold_mode="sequential"`` (the default) is the bitwise oracle;
   ``fold_mode="associative"`` replays the same trajectory within fp
   tolerance for every affine strategy, window size, seed, and trace —
-  and *bitwise* on single-fold ticks, where the prefix scan evaluates
-  the identical op sequence (no reassociation happens);
+  and *bitwise* on single-fold ticks, which run the sequential step
+  itself (a one-slot bucket has no prefix to parallelize);
 * ``"auto"`` degrades to the sequential scan on CPU (bitwise);
 * forcing ``"associative"`` on a non-affine fold (asofed with the
   Eq. 5-6 feature pass) fails fast, as does a typo'd mode;
@@ -108,8 +108,8 @@ def test_associative_matches_sequential_sweep(alg, over, seed, window):
 
 
 def test_associative_single_fold_bitwise():
-    """max_cohort=1 ticks hold exactly one fold: the prefix scan runs the
-    same mul/mul/add sequence as the sequential step, so fedasync must be
+    """max_cohort=1 ticks hold exactly one fold, which the associative
+    mode runs through the sequential step itself, so fedasync must be
     bit-identical — fp reassociation only enters at fold depth >= 2."""
     cfg_model, model, mk = _setup()
     seq = _trace("fedasync", model, cfg_model, mk(), CFG, max_cohort=1)

@@ -5,7 +5,8 @@ Runs in a subprocess with XLA_FLAGS=--xla_force_host_platform_device_count=4
 Validates that the data-mesh path — stacked client state sharded over
 ``data``, shard_map'd vmapped local rounds, replicated fold scan — replays
 the single-device trajectory (and hence the sequential per-arrival
-reference) within fp32 tolerance, for asofed and fedasync.
+reference) within fp32 tolerance, for asofed and fedasync (the latter
+also on the associative fold's linear_scan kernel).
 """
 import json
 import os
@@ -42,14 +43,20 @@ _SCRIPT = textwrap.dedent(
     cfg = RunConfig(T=24, batch_size=4, local_epochs=2, eta=0.02, lam=1.0,
                     beta=0.001, task="regression", eval_every=12, seed=0)
 
+    # the associative fold on the linear_scan kernel, run replicated
+    # inside an explicit SPMD region on the mesh
+    assoc = dataclasses.replace(cfg, fold_mode="associative",
+                                fold_kernel=True, fold_kernel_interpret=True)
     out = {}
-    for alg in ("asofed", "fedasync"):
+    for name, alg, c in (("asofed", "asofed", cfg),
+                         ("fedasync", "fedasync", cfg),
+                         ("fedasync-assoc", "fedasync", assoc)):
         tr_sharded, tr_single = [], []
         run_strategy(get_strategy(alg), model, cfg_model,
-                     make_sim_clients(data, seed=0), cfg,
+                     make_sim_clients(data, seed=0), c,
                      trace=tr_sharded, mesh="auto")
         run_strategy(get_strategy(alg), model, cfg_model,
-                     make_sim_clients(data, seed=0), cfg,
+                     make_sim_clients(data, seed=0), c,
                      trace=tr_single, mesh=None)
         assert len(tr_sharded) == len(tr_single) >= 2, alg
         err = 0.0
@@ -57,7 +64,7 @@ _SCRIPT = textwrap.dedent(
             assert t1 == t2
             for a, b in zip(jax.tree.leaves(w1), jax.tree.leaves(w2)):
                 err = max(err, float(np.max(np.abs(a - b))))
-        out[alg] = {"ticks": len(tr_sharded), "max_err": err}
+        out[name] = {"ticks": len(tr_sharded), "max_err": err}
     print("RESULT" + json.dumps(out))
     """
 )
@@ -70,7 +77,8 @@ def test_sharded_engine_matches_single_device():
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          env.get("PYTHONPATH", "")]
     ).rstrip(os.pathsep)
-    env.pop("JAX_PLATFORMS", None)
+    # four virtual CPU devices; a test child never claims a chip
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-c", _SCRIPT], env=env,
         capture_output=True, text=True, timeout=900,
