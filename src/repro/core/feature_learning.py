@@ -13,6 +13,8 @@ from __future__ import annotations
 
 from typing import Any, Optional, Sequence, Tuple
 
+import jax
+
 from repro.configs.base import ModelConfig
 from repro.kernels.feature_attention.ops import feature_attention
 
@@ -56,5 +58,7 @@ def apply_feature_learning(params, cfg: ModelConfig, *,
     """
     path = first_layer_path(cfg)
     w1 = _get(params, path)
-    w1 = feature_attention(w1, use_kernel=use_kernel, interpret=interpret)
+    with jax.named_scope("feature_pass"):
+        w1 = feature_attention(w1, use_kernel=use_kernel,
+                               interpret=interpret)
     return _set(params, path, w1)

@@ -71,9 +71,11 @@ compiled once per (model, config) rather than once per runner.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import time
+import types
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -89,7 +91,8 @@ from repro.sim.prefetch import TickBuilder, TickPrefetcher, bucket_size
 from repro.sim.profiles import SimClient
 from repro.sim.scheduler import AsyncScheduler, SyncScheduler, SweepScheduler
 from repro.sim.streaming import OnlineStream
-from repro.sim.telemetry import TelemetryLog, split_at_evals
+from repro.sim.telemetry import (FullCollections, TelemetryLog, span,
+                                 split_at_evals)
 from repro.sim.traces import utilization as availability_utilization
 from repro.sim.workloads import resolve_eval_report
 
@@ -210,6 +213,10 @@ class RunConfig:
 
 @dataclasses.dataclass
 class HistoryPoint:
+    """One eval of a run.  ``wall_time`` is the run's wall seconds when the
+    eval was *dispatched* (its predict is enqueued and its metrics are
+    read after the run), not when its answers were ready."""
+
     global_iter: int
     sim_time: float
     wall_time: float
@@ -459,7 +466,10 @@ def run_strategy(
     eval-params-as-numpy)`` after every dispatch — the hook the
     equivalence tests use.  ``stats``, when a dict, is filled with
     ``{"ticks", "windows", "iters", "sim_time"}`` counters plus the
-    per-phase wall breakdown ``{"host_build_s", "device_s", "eval_s"}``,
+    per-phase wall breakdown ``{"host_build_s", "device_s", "eval_s",
+    "window_wait_s", "run_setup_s"}`` (each the summed seconds of one
+    ``repro.*`` span, see :func:`repro.sim.telemetry.span`), the count of
+    full garbage collections in the run (``"gc_full_collections"``),
     the ``{"prefetch", "devices", "window", "state_dtype",
     "tick_cache_size"}`` run descriptors, the ``{"stacked_state_bytes",
     "peak_live_device_bytes"}`` memory columns (benchmark hooks), and the
@@ -478,584 +488,627 @@ def run_strategy(
     therefore the final weights, bit-for-bit against an uninterrupted
     run (its ``history`` covers only post-resume evals).
     """
-    clients = list(clients)
-    K = len(clients)
-    # client cids index rows of the stacked state pytree (and the server's
-    # per-client count arrays): require the dense 0..K-1 layout up front —
-    # JAX gather/scatter would clamp a stray cid silently, not raise
-    if [c.cid for c in clients] != list(range(K)):
-        raise ValueError(
-            "run_strategy requires clients with cid == position "
-            f"(0..{K - 1}); got {[c.cid for c in clients]}"
-        )
-    if mesh == "auto":
-        mesh = sharding_lib.data_mesh()
-    E, B = cfg.local_epochs, cfg.batch_size
-    max_cohort = max_cohort if max_cohort is not None else cfg.max_cohort
-    W = max(1, int(window if window is not None else cfg.window))
-    # fail-fast validation before any compile/run cost: a typo'd dtype,
-    # task, or workload name must raise readably, not ride silently into
-    # the stats/BENCH columns (or report the wrong task's metrics)
-    dtypes_lib.resolve_state_dtype(cfg.state_dtype)
-    if cfg.state_residency not in ("device", "host"):
-        raise ValueError(
-            f"unknown state_residency {cfg.state_residency!r}; "
-            "accepted: 'device' | 'host'")
-    if cfg.state_residency == "host" and strategy.schedule != "async":
-        raise ValueError(
-            "state_residency='host' is supported for async schedules only "
-            f"({strategy.name!r} is {strategy.schedule!r}): the host pool "
-            "rides the windowed gather/scatter tick path")
-    if cfg.state_residency == "host" and (strategy.eval_per_client
-                                          or strategy.pooled):
-        raise ValueError(
-            f"state_residency='host' cannot serve {strategy.name!r}: "
-            "per-client / pooled evaluation reads the full stacked state, "
-            "which a host-resident pool keeps off-device")
-    if cfg.state_shards < 1:
-        raise ValueError(
-            f"state_shards must be >= 1, got {cfg.state_shards}")
-    if cfg.eval_every < 0:
-        raise ValueError(
-            f"eval_every must be >= 0 (0 disables evaluation), "
-            f"got {cfg.eval_every}")
-    eval_report = resolve_eval_report(cfg)
-    # chaos layer: any client carrying an active FaultSpec switches the
-    # compiled tick to fault-aware mode (crash-restart state resets, wire
-    # corruption, duplicate double-folds); the server admission guards
-    # activate with it or with the explicit cfg knobs.  Both are
-    # compile-time flags, so a fault-free config traces the exact
-    # pre-chaos tick and replays bitwise.
-    faults_on = any(
-        c.profile.faults is not None and c.profile.faults.active
-        for c in clients)
-    if cfg.staleness_policy not in ("reject", "downweight"):
-        raise ValueError(
-            f"unknown staleness_policy {cfg.staleness_policy!r}; "
-            "accepted: 'reject' | 'downweight'")
-    guards = cfg.max_staleness is not None or cfg.max_delta_norm is not None
-    chaos = faults_on or guards
-    if (checkpoint_path is not None or resume_from is not None) \
-            and strategy.schedule != "async":
-        raise ValueError(
-            "run checkpointing / resume is supported for async schedules "
-            f"only ({strategy.name!r} is {strategy.schedule!r})")
-    # ... and so must an unknown fold_mode, or fold_mode="associative"
-    # with a strategy that declines the affine fold form (under faults,
-    # additionally one whose closed form is not dup/reject-composable)
-    compile_lib.resolve_fold_affine(strategy, model, cfg_model, cfg,
-                                    faults_on=faults_on)
-    # ... and an unknown upload codec / out-of-range knobs, or a lossy
-    # codec on a strategy with no compressible upload.  (Imported here:
-    # the strategy modules import Strategy from this module, so a
-    # top-level import of repro.core would be circular.)
-    from repro.core.algorithms.common import resolve_upload_codec
-
-    ucodec = resolve_upload_codec(cfg)
-    uview = strategy.upload_codec_view(model, cfg)
-    if not ucodec.identity and uview is None:
-        raise ValueError(
-            f"upload_codec={cfg.upload_codec!r} requires a strategy with "
-            f"a compressible upload, but {strategy.name!r} provides no "
-            "upload_codec_view (the Local/Global sweep baselines upload "
-            "nothing)")
-    if chaos and strategy.schedule != "sweep" and uview is None:
-        raise ValueError(
-            "fault injection / admission guards act on the strategy's "
-            f"wire-delta view, but {strategy.name!r} provides no "
-            "upload_codec_view")
-    if faults_on and strategy.schedule == "async" \
-            and strategy.build_init_client(model, cfg) is None:
-        raise ValueError(
-            f"fault injection needs {strategy.name!r} to provide "
-            "build_init_client: crash-restart rebuilds the crashed "
-            "client's state row inside the jitted tick")
-    w0 = model.init(jax.random.PRNGKey(cfg.seed))
-    codec = strategy.state_codec(model, cfg, w0)
-    # simulated wire cost of one arrival's (encoded) upload — a pure
-    # function of codec config and model leaf shapes, fed to the
-    # schedulers' bandwidth-metered delay draws.  Strategies without an
-    # upload (sweep baselines) transmit nothing.
-    upload_bytes = ucodec.tree_bytes(w0) if uview is not None else 0.0
-    client_slots = tuple(strategy.telemetry_slots(cfg))
-    server_slots = tuple(strategy.server_telemetry_slots(cfg))
-    # the engine-owned fold-depth slot rides between the two blocks;
-    # chaos runs append the admission counters to the in-scan row (the
-    # condition mirrors tick_body's: guards need a fold + a wire view)
-    chaos_tick = (chaos and uview is not None
-                  and strategy.build_fold(model, cfg_model, cfg) is not None)
-    slots = client_slots + ("folds_per_tick",) + server_slots
-    if chaos_tick:
-        slots = slots + ("rejected_per_tick", "clipped_per_tick")
-    drop = cfg.dropout_frac if strategy.uses_dropout else 0.0
-    skip = cfg.periodic_dropout if strategy.uses_dropout else 0.0
-
-    if strategy.schedule == "async":
-        sched = AsyncScheduler(
-            clients, seed=cfg.seed, dropout_frac=drop, skip_prob=skip,
-            init_work=B, round_work=E * B, sim_time_budget=cfg.sim_time_budget,
-            upload_bytes=upload_bytes,
-        )
-        active = sched.active
-        pad = max(1, min(max_cohort or len(active), max(len(active), 1)))
-    elif strategy.schedule == "sync":
-        sched = SyncScheduler(
-            clients, seed=cfg.seed, dropout_frac=drop, skip_prob=skip,
-            participation=cfg.participation, round_work=E * B,
-            upload_bytes=upload_bytes,
-        )
-        active = sched.active
-        pad = sched.m
-    else:  # sweep
-        sched = SweepScheduler(clients)
-        active = sched.active
-        pad = 1 if strategy.pooled else K
-
-    n_members = 1 if strategy.pooled else K
-    members = [None] if strategy.pooled else clients
-    scratch = n_members  # index of the scratch row targeted by padded slots
-    n_rows = n_members + 1
-    if mesh is not None:
-        # extra scratch rows so the client axis divides the mesh evenly
-        D = mesh.devices.size
-        n_rows = -(-n_rows // D) * D
-
-    def _n0(c: Optional[SimClient]) -> float:
-        return float(c.stream.visible(0)) if c is not None else 0.0
-
-    init_batched = compile_lib.batched_init_fn(strategy, model, cfg)
-    pool = None
-    if cfg.state_residency == "host":
-        if init_batched is None:
+    with span("repro.run"), FullCollections() as full_collections, \
+            contextlib.ExitStack() as setup:
+        # everything up to the first window request is the run's set-up:
+        # `setup.close()` ends it there
+        phases = types.SimpleNamespace(run_setup_s=0.0, window_wait_s=0.0,
+                                       device_s=0.0, eval_s=0.0)
+        setup.enter_context(span("repro.run.setup", phases, "run_setup_s"))
+        clients = list(clients)
+        K = len(clients)
+        # client cids index rows of the stacked state pytree (and the
+        # server's per-client count arrays): require the dense 0..K-1
+        # layout up front — JAX gather/scatter would clamp a stray cid
+        # silently, not raise
+        if [c.cid for c in clients] != list(range(K)):
             raise ValueError(
-                f"state_residency='host' needs {strategy.name!r} to "
-                "provide build_init_client: the pool is filled by chunked "
-                "batched init (a device-stacked init of all K rows is "
-                "exactly what the host pool exists to avoid)")
-        from repro.sim.state_pool import HostStatePool
+                "run_strategy requires clients with cid == position "
+                f"(0..{K - 1}); got {[c.cid for c in clients]}"
+            )
+        if mesh == "auto":
+            mesh = sharding_lib.data_mesh()
+        E, B = cfg.local_epochs, cfg.batch_size
+        max_cohort = max_cohort if max_cohort is not None else cfg.max_cohort
+        W = max(1, int(window if window is not None else cfg.window))
+        # fail-fast validation before any compile/run cost: a typo'd dtype,
+        # task, or workload name must raise readably, not ride silently into
+        # the stats/BENCH columns (or report the wrong task's metrics)
+        dtypes_lib.resolve_state_dtype(cfg.state_dtype)
+        if cfg.state_residency not in ("device", "host"):
+            raise ValueError(
+                f"unknown state_residency {cfg.state_residency!r}; "
+                "accepted: 'device' | 'host'")
+        if cfg.state_residency == "host" and strategy.schedule != "async":
+            raise ValueError(
+                "state_residency='host' is supported for async schedules only "
+                f"({strategy.name!r} is {strategy.schedule!r}): the host pool "
+                "rides the windowed gather/scatter tick path")
+        if cfg.state_residency == "host" and (strategy.eval_per_client
+                                              or strategy.pooled):
+            raise ValueError(
+                f"state_residency='host' cannot serve {strategy.name!r}: "
+                "per-client / pooled evaluation reads the full stacked state, "
+                "which a host-resident pool keeps off-device")
+        if cfg.state_shards < 1:
+            raise ValueError(
+                f"state_shards must be >= 1, got {cfg.state_shards}")
+        if cfg.eval_every < 0:
+            raise ValueError(
+                f"eval_every must be >= 0 (0 disables evaluation), "
+                f"got {cfg.eval_every}")
+        eval_report = resolve_eval_report(cfg)
+        # chaos layer: any client carrying an active FaultSpec switches the
+        # compiled tick to fault-aware mode (crash-restart state resets, wire
+        # corruption, duplicate double-folds); the server admission guards
+        # activate with it or with the explicit cfg knobs.  Both are
+        # compile-time flags, so a fault-free config traces the exact
+        # pre-chaos tick and replays bitwise.
+        faults_on = any(
+            c.profile.faults is not None and c.profile.faults.active
+            for c in clients)
+        if cfg.staleness_policy not in ("reject", "downweight"):
+            raise ValueError(
+                f"unknown staleness_policy {cfg.staleness_policy!r}; "
+                "accepted: 'reject' | 'downweight'")
+        guards = (cfg.max_staleness is not None
+                  or cfg.max_delta_norm is not None)
+        chaos = faults_on or guards
+        if (checkpoint_path is not None or resume_from is not None) \
+                and strategy.schedule != "async":
+            raise ValueError(
+                "run checkpointing / resume is supported for async schedules "
+                f"only ({strategy.name!r} is {strategy.schedule!r})")
+        # ... and so must an unknown fold_mode, or fold_mode="associative"
+        # with a strategy that declines the affine fold form (under faults,
+        # additionally one whose closed form is not dup/reject-composable)
+        compile_lib.resolve_fold_affine(strategy, model, cfg_model, cfg,
+                                        faults_on=faults_on)
+        # ... and an unknown upload codec / out-of-range knobs, or a lossy
+        # codec on a strategy with no compressible upload.  (Imported here:
+        # the strategy modules import Strategy from this module, so a
+        # top-level import of repro.core would be circular.)
+        from repro.core.algorithms.common import resolve_upload_codec
 
-        storage = dtypes_lib.resolve_state_storage(cfg.state_dtype)
-        packed = (storage is not None and codec is not None
-                  and storage.pool_bits == 4)
-        tmpl = init_batched(
-            w0, jnp.asarray(np.array([_n0(members[0])], np.float32)))
-        if codec is not None:
-            tmpl = codec.encode(tmpl)
-        pool = HostStatePool(
-            jax.tree.map(lambda x: np.asarray(x[0]), tmpl), n_members,
-            packed=packed, shards=min(cfg.state_shards, n_members))
-        # chunked init: device footprint of one chunk at a time, encoded
-        # and streamed into the pool (the K=10^6 setup path)
-        CHUNK = 4096
-        s = 0
-        while s < n_members:
-            e = min(s + CHUNK, n_members)
-            n0c = np.array([_n0(c) for c in members[s:e]], np.float32)
-            chunk = init_batched(w0, jnp.asarray(n0c))
-            if codec is not None:
-                chunk = codec.encode(chunk)
-            pool.write_block(s, jax.tree.map(np.asarray, chunk))
-            s = e
-        stacked = None  # no device-resident stack: blocks ride per window
-    elif init_batched is not None:
-        n0s = np.array([_n0(c) for c in members]
-                       + [_n0(members[0])] * (n_rows - n_members), np.float32)
-        stacked = init_batched(w0, jnp.asarray(n0s))
-    else:
-        from repro.common.pytree import tree_stack
+        ucodec = resolve_upload_codec(cfg)
+        uview = strategy.upload_codec_view(model, cfg)
+        if not ucodec.identity and uview is None:
+            raise ValueError(
+                f"upload_codec={cfg.upload_codec!r} requires a strategy with "
+                f"a compressible upload, but {strategy.name!r} provides no "
+                "upload_codec_view (the Local/Global sweep baselines upload "
+                "nothing)")
+        if chaos and strategy.schedule != "sweep" and uview is None:
+            raise ValueError(
+                "fault injection / admission guards act on the strategy's "
+                f"wire-delta view, but {strategy.name!r} provides no "
+                "upload_codec_view")
+        if faults_on and strategy.schedule == "async" \
+                and strategy.build_init_client(model, cfg) is None:
+            raise ValueError(
+                f"fault injection needs {strategy.name!r} to provide "
+                "build_init_client: crash-restart rebuilds the crashed "
+                "client's state row inside the jitted tick")
+        w0 = model.init(jax.random.PRNGKey(cfg.seed))
+        codec = strategy.state_codec(model, cfg, w0)
+        # simulated wire cost of one arrival's (encoded) upload — a pure
+        # function of codec config and model leaf shapes, fed to the
+        # schedulers' bandwidth-metered delay draws.  Strategies without an
+        # upload (sweep baselines) transmit nothing.
+        upload_bytes = ucodec.tree_bytes(w0) if uview is not None else 0.0
+        client_slots = tuple(strategy.telemetry_slots(cfg))
+        server_slots = tuple(strategy.server_telemetry_slots(cfg))
+        # the engine-owned fold-depth slot rides between the two blocks;
+        # chaos runs append the admission counters to the in-scan row (the
+        # condition mirrors tick_body's: guards need a fold + a wire view)
+        chaos_tick = (
+            chaos and uview is not None
+            and strategy.build_fold(model, cfg_model, cfg) is not None)
+        slots = client_slots + ("folds_per_tick",) + server_slots
+        if chaos_tick:
+            slots = slots + ("rejected_per_tick", "clipped_per_tick")
+        drop = cfg.dropout_frac if strategy.uses_dropout else 0.0
+        skip = cfg.periodic_dropout if strategy.uses_dropout else 0.0
 
-        states = [strategy.init_client(model, cfg, w0, c) for c in members]
-        states += [strategy.init_client(model, cfg, w0, members[0])
-                   ] * (n_rows - n_members)
-        stacked = tree_stack(states)
-    if codec is not None and stacked is not None:
-        stacked = codec.encode(stacked)  # one-time: state lives compressed
-    server = strategy.init_server(model, cfg_model, cfg, w0, clients, active)
-    if mesh is not None:
-        if stacked is not None:
-            stacked = jax.device_put(stacked, jax.tree.map(
-                lambda x: sharding_lib.client_sharding(x.shape, mesh),
-                stacked))
-        server = jax.device_put(server, sharding_lib.replicated(mesh))
-    windowed = strategy.schedule == "async"
-    tick_fn = compile_lib.tick_fn(strategy, model, cfg_model, cfg, K, mesh,
-                                  windowed=windowed, codec=codec,
-                                  slots=client_slots,
-                                  server_slots=server_slots,
-                                  faults_on=faults_on)
-    # eval_every=0 disables evaluation entirely: no padded [K, n_max]
-    # test tensor ever lands on device (the K-sweep bench path, where
-    # device memory must stay bounded by the active cohort, not K)
-    evaluator = Evaluator(model, clients, eval_report,
-                          strategy.eval_per_client) \
-        if cfg.eval_every > 0 else None
-    telem = telemetry if telemetry is not None else TelemetryLog(slots)
-    if telem.slots != slots:
-        telem.slots = slots  # caller-constructed logs adopt the run's slots
-    by_id = {c.cid: c for c in clients}
+        if strategy.schedule == "async":
+            sched = AsyncScheduler(
+                clients, seed=cfg.seed, dropout_frac=drop, skip_prob=skip,
+                init_work=B, round_work=E * B,
+                sim_time_budget=cfg.sim_time_budget,
+                upload_bytes=upload_bytes,
+            )
+            active = sched.active
+            pad = max(1, min(max_cohort or len(active), max(len(active), 1)))
+        elif strategy.schedule == "sync":
+            sched = SyncScheduler(
+                clients, seed=cfg.seed, dropout_frac=drop, skip_prob=skip,
+                participation=cfg.participation, round_work=E * B,
+                upload_bytes=upload_bytes,
+            )
+            active = sched.active
+            pad = sched.m
+        else:  # sweep
+            sched = SweepScheduler(clients)
+            active = sched.active
+            pad = 1 if strategy.pooled else K
 
-    def transfer(name, arr):
-        sh = sharding_lib.client_sharding(arr.shape, mesh)
-        return jnp.asarray(arr) if sh is None else jax.device_put(arr, sh)
+        n_members = 1 if strategy.pooled else K
+        members = [None] if strategy.pooled else clients
+        scratch = n_members  # the scratch row targeted by padded slots
+        n_rows = n_members + 1
+        if mesh is not None:
+            # extra scratch rows so the client axis divides the mesh evenly
+            D = mesh.devices.size
+            n_rows = -(-n_rows // D) * D
 
-    def window_transfer(name, arr):
-        sh = sharding_lib.window_sharding(arr.shape, mesh)
-        return jnp.asarray(arr) if sh is None else jax.device_put(arr, sh)
+        def _n0(c: Optional[SimClient]) -> float:
+            return float(c.stream.visible(0)) if c is not None else 0.0
 
-    builder = TickBuilder(
-        by_id=by_id, batch_size=B, local_epochs=E, scratch=scratch, pad=pad,
-        pooled=strategy.pooled, transfer=transfer,
-        window_transfer=window_transfer, state_pool=pool,
-    )
-    # under host residency the device-side state is the per-window cohort
-    # block, not the [K, ...] stack: the column reports the largest block
-    # actually dispatched (updated in `dispatch`), so it is what it claims
-    # to be — live device bytes of client state — in both modes
-    stacked_state_bytes = 0 if pool is not None else sum(
-        int(x.size) * jnp.dtype(x.dtype).itemsize
-        for x in jax.tree.leaves(stacked))
-    peak_live = _live_device_bytes()
+        with span("repro.setup.init_state"):
+            init_batched = compile_lib.batched_init_fn(strategy, model, cfg)
+            pool = None
+            if cfg.state_residency == "host":
+                if init_batched is None:
+                    raise ValueError(
+                        f"state_residency='host' needs {strategy.name!r} to "
+                        "provide build_init_client: the pool is filled by "
+                        "chunked batched init (a device-stacked init of all K "
+                        "rows is exactly what the host pool exists to avoid)")
+                from repro.sim.state_pool import HostStatePool
 
-    history: List[HistoryPoint] = []
-    pending_evals: List[Tuple[int, float, float, Any]] = []
-    device_s = 0.0
-    eval_s = 0.0
-    n_ticks, n_windows, t, sim_time = 0, 0, 0, 0.0
-    n_uploads = 0  # folded arrivals (each transmits one encoded delta)
-    t0 = time.perf_counter()
+                storage = dtypes_lib.resolve_state_storage(cfg.state_dtype)
+                packed = (storage is not None and codec is not None
+                          and storage.pool_bits == 4)
+                tmpl = init_batched(
+                    w0, jnp.asarray(np.array([_n0(members[0])], np.float32)))
+                if codec is not None:
+                    tmpl = codec.encode(tmpl)
+                pool = HostStatePool(
+                    jax.tree.map(lambda x: np.asarray(x[0]), tmpl), n_members,
+                    packed=packed, shards=min(cfg.state_shards, n_members))
+                # chunked init: device footprint of one chunk at a time,
+                # encoded and streamed into the pool (the K=10^6 setup path)
+                CHUNK = 4096
+                s = 0
+                while s < n_members:
+                    e = min(s + CHUNK, n_members)
+                    n0c = np.array([_n0(c) for c in members[s:e]], np.float32)
+                    chunk = init_batched(w0, jnp.asarray(n0c))
+                    if codec is not None:
+                        chunk = codec.encode(chunk)
+                    pool.write_block(s, jax.tree.map(np.asarray, chunk))
+                    s = e
+                stacked = None  # no device-resident stack: per-window blocks
+            elif init_batched is not None:
+                n0s = np.array([_n0(c) for c in members]
+                               + [_n0(members[0])] * (n_rows - n_members),
+                               np.float32)
+                stacked = init_batched(w0, jnp.asarray(n0s))
+            else:
+                from repro.common.pytree import tree_stack
 
-    def eval_params():
-        if pool is not None:  # host residency: central-model eval only
-            return strategy.eval_params(server, None)
-        members_view = jax.tree.map(lambda x: x[:n_members], stacked)
-        if codec is not None and (strategy.eval_per_client or strategy.pooled):
-            members_view = codec.decode(members_view)
-        return strategy.eval_params(server, members_view)
-
-    def record(t: int, sim_time: float):
-        nonlocal eval_s
-        if evaluator is None:
-            return
-        e0 = time.perf_counter()
-        preds = evaluator.predict_device(eval_params())
-        pending_evals.append((t, sim_time, time.perf_counter() - t0, preds))
-        eval_s += time.perf_counter() - e0
-
-    def dispatch(pt):
-        nonlocal stacked, server, device_s, n_ticks, n_windows, peak_live, \
-            stacked_state_bytes
-        d0 = time.perf_counter()
-        if pool is not None:
-            # host residency: repair the speculative gather (rows written
-            # by scatters that landed after it), move the cohort block to
-            # device, run the megastep on it as the stacked carry, and
-            # scatter the updated member rows back into the pool
-            pool.patch(pt.block, pt.block_cids, pt.gather_seq)
-            block = jax.tree.map(lambda x: transfer("block", x), pt.block)
-            stacked_state_bytes = max(stacked_state_bytes, sum(
-                int(x.size) * jnp.dtype(x.dtype).itemsize
-                for x in jax.tree.leaves(block)))
-            block, server, tel = tick_fn(block, server, *pt.arrays)
-            jax.block_until_ready((block, server))
-            pool.scatter(pt.block_cids[:pt.block_rows],
-                         jax.tree.map(np.asarray, block))
-        else:
-            stacked, server, tel = tick_fn(stacked, server, *pt.arrays)
-            jax.block_until_ready((stacked, server))
-        telem.append(pt, tel)
-        device_s += time.perf_counter() - d0
-        n_ticks += pt.n_ticks
-        n_windows += 1
-        if n_windows <= 2:  # steady-state live-set snapshot, off the hot path
-            peak_live = max(peak_live, _live_device_bytes())
-
-    use_prefetch = False
-    resume_t = 0
-    if strategy.schedule == "async":
-        # a client with an empty local split (visible == 0 forever) can
-        # never train: its arrivals are dropped so fabricated zero batches
-        # are never folded in (FedAsync mixes at full weight, without the
-        # n_vis/N guard ASO-Fed has)
-        trainable = {c.cid for c in active if c.stream.n > 0}
-        if resume_from is not None:
-            from repro import checkpoint as ckpt_lib
-
-            stacked, server, host = ckpt_lib.load_run_state(
-                resume_from, stacked, server, pool=pool)
-            if host.get("strategy") != strategy.name \
-                    or int(host.get("seed", cfg.seed)) != cfg.seed:
-                raise ValueError(
-                    f"snapshot at {resume_from!r} was written by "
-                    f"strategy={host.get('strategy')!r} "
-                    f"seed={host.get('seed')}; this run is "
-                    f"{strategy.name!r} seed={cfg.seed}")
+                states = [strategy.init_client(model, cfg, w0, c)
+                          for c in members]
+                states += [strategy.init_client(model, cfg, w0, members[0])
+                           ] * (n_rows - n_members)
+                stacked = tree_stack(states)
+            if codec is not None and stacked is not None:
+                # one-time: the state lives compressed
+                stacked = codec.encode(stacked)
+            server = strategy.init_server(model, cfg_model, cfg, w0, clients,
+                                          active)
             if mesh is not None:
                 if stacked is not None:
                     stacked = jax.device_put(stacked, jax.tree.map(
-                        lambda x: sharding_lib.client_sharding(
-                            x.shape, mesh), stacked))
-                server = jax.device_put(server,
-                                        sharding_lib.replicated(mesh))
-            sched.load_state_dict(host["sched"])
-            for cid_s, st_rng in host["streams"].items():
-                by_id[int(cid_s)].stream.set_rng_state(st_rng)
-            builder.staleness.load_state_dict(host["staleness"])
-            resume_t = int(host["t"])
-            t = resume_t
-            sim_time = float(host["sim_time"])
-        # adaptive default: the prefetch thread overlaps host batch
-        # building with device execution, which is a pure win on
-        # accelerators and multi-core hosts — but on <4-core CPU boxes
-        # the builder steals cycles from XLA itself and the "overlap" is
-        # negative-sum.  Trajectories are bit-identical either way (the
-        # speculation contract), so the default is free to choose.
-        try:  # affinity respects container/cgroup CPU limits; cpu_count
-            ncpu = len(os.sched_getaffinity(0))  # does not
-        except AttributeError:
-            ncpu = os.cpu_count() or 1
-        use_prefetch = (prefetch if prefetch is not None
-                        else cfg.prefetch if cfg.prefetch is not None
-                        else jax.default_backend() != "cpu" or ncpu >= 4)
+                        lambda x: sharding_lib.client_sharding(x.shape, mesh),
+                        stacked))
+                server = jax.device_put(server, sharding_lib.replicated(mesh))
+        windowed = strategy.schedule == "async"
+        with span("repro.setup.tick_fn"):
+            tick_fn = compile_lib.tick_fn(
+                strategy, model, cfg_model, cfg, K, mesh, windowed=windowed,
+                codec=codec, slots=client_slots, server_slots=server_slots,
+                faults_on=faults_on)
+        # eval_every=0 disables evaluation entirely: no padded [K, n_max]
+        # test tensor ever lands on device (the K-sweep bench path, where
+        # device memory must stay bounded by the active cohort, not K)
+        evaluator = None
+        if cfg.eval_every > 0:
+            with span("repro.setup.evaluator"):
+                evaluator = Evaluator(model, clients, eval_report,
+                                      strategy.eval_per_client)
+        telem = telemetry if telemetry is not None else TelemetryLog(slots)
+        if telem.slots != slots:
+            telem.slots = slots  # a caller's log adopts the run's slots
+        by_id = {c.cid: c for c in clients}
 
-        def produce():
-            """Pop + filter + build each window (worker thread when
-            prefetching).  Mirrors the consuming loop's termination logic
-            exactly, so at most the single in-flight speculative peek is
-            ever un-committed.  ``total_limit`` caps *popped* arrivals at
-            the remaining iteration budget — for W == 1 this is exactly
-            the old per-tick ``peek_tick(min(pad, T - tp))`` stream.
+        def transfer(name, arr):
+            sh = sharding_lib.client_sharding(arr.shape, mesh)
+            return jnp.asarray(arr) if sh is None else jax.device_put(arr, sh)
 
-            A window is split into maximal runs of *same-bucket* ticks
-            (one fused ``lax.scan`` block per run): a tick must execute
-            at exactly the shape bucket it would ride at W == 1, because
-            XLA's lowering is shape-dependent and inflating a small tick
-            to a larger bucket would break the window-on/off bitwise
-            replay.  In the steady state arrivals-per-tick is stable, so
-            runs span whole windows; bucket switches (the first
-            full-cohort tick, the drained tail, churn) cost one extra
-            dispatch each — never a wrong bit.  With ``cfg.eval_align``
-            windows are first split at ``eval_every`` fold boundaries
-            (``repro.sim.telemetry.split_at_evals``), so the consuming
-            loop's eval check fires at exactly the ticks a window=1 run
-            would evaluate after — a dispatch-count trade, still never a
-            wrong bit.
-            """
-            tp = resume_t
-            sim_prod = float(sim_time)
-            # the iteration budget advances per *fold*: charge it only
-            # for trainable arrivals, so every in-window tick limit
-            # equals the one a window=1 producer would compute (dropped
-            # empty-split clients must not perturb tick membership)
-            kept_count = lambda tk: sum(  # noqa: E731
-                a.cid in trainable for a in tk)
-            while tp < cfg.T:
-                snap = None
-                if checkpoint_path is not None:
-                    # full host cursor, captured at the only clean point:
-                    # the previous window is committed (no speculation in
-                    # flight) and no stream rng draw for the upcoming
-                    # window has been consumed.  It rides the window's
-                    # first PreparedTick to the consumer, which persists
-                    # it together with the device state *before*
-                    # dispatching that tick.
-                    snap = {
-                        "t": tp, "sim_time": sim_prod,
-                        "strategy": strategy.name, "seed": cfg.seed,
-                        "state_residency": cfg.state_residency,
-                        "sched": sched.state_dict(),
-                        "streams": {str(c.cid): c.stream.rng_state()
-                                    for c in active},
-                        "staleness": builder.staleness.state_dict(),
-                    }
-                ticks = sched.peek_window(W, pad, total_limit=cfg.T - tp,
-                                          count=kept_count)
-                if not ticks:
-                    sched.commit()
-                    break  # drained or over the simulated-time budget
-                kept = [[a for a in tk if a.cid in trainable] for tk in ticks]
-                kept = [tk for tk in kept if tk]
-                if not kept:
-                    sched.commit()
-                    continue  # window held only empty-split clients
-                sched.commit()
-                if cfg.eval_align and W > 1 and cfg.eval_every > 0:
-                    segments = split_at_evals(kept, tp, cfg.eval_every,
-                                              count=kept_count)
+        def window_transfer(name, arr):
+            sh = sharding_lib.window_sharding(arr.shape, mesh)
+            return jnp.asarray(arr) if sh is None else jax.device_put(arr, sh)
+
+        builder = TickBuilder(
+            by_id=by_id, batch_size=B, local_epochs=E, scratch=scratch,
+            pad=pad,
+            pooled=strategy.pooled, transfer=transfer,
+            window_transfer=window_transfer, state_pool=pool,
+        )
+        # under host residency the device-side state is the per-window cohort
+        # block, not the [K, ...] stack: the column reports the largest block
+        # actually dispatched (updated in `dispatch`), so it is what it claims
+        # to be — live device bytes of client state — in both modes
+        stacked_state_bytes = 0 if pool is not None else sum(
+            int(x.size) * jnp.dtype(x.dtype).itemsize
+            for x in jax.tree.leaves(stacked))
+        peak_live = _live_device_bytes()
+
+        history: List[HistoryPoint] = []
+        pending_evals: List[Tuple[int, float, float, Any]] = []
+        n_ticks, n_windows, t, sim_time = 0, 0, 0, 0.0
+        n_uploads = 0  # folded arrivals (each transmits one encoded delta)
+        t0 = time.perf_counter()
+
+        def eval_params():
+            if pool is not None:  # host residency: central-model eval only
+                return strategy.eval_params(server, None)
+            members_view = jax.tree.map(lambda x: x[:n_members], stacked)
+            if codec is not None and (strategy.eval_per_client
+                                      or strategy.pooled):
+                members_view = codec.decode(members_view)
+            return strategy.eval_params(server, members_view)
+
+        def record(t: int, sim_time: float):
+            if evaluator is None:
+                return
+            with span("repro.eval.predict", phases, "eval_s"):
+                preds = evaluator.predict_device(eval_params())
+                pending_evals.append((t, sim_time, time.perf_counter() - t0,
+                                      preds))
+
+        def dispatch(pt):
+            nonlocal stacked, server, n_ticks, n_windows, peak_live, \
+                stacked_state_bytes
+            with span("repro.dispatch", phases, "device_s",
+                      bucket=pt.arrays[0].shape[-1], n_ticks=pt.n_ticks,
+                      window=n_windows):
+                if pool is not None:
+                    # host residency: repair the speculative gather (rows
+                    # written by scatters that landed after it), move the
+                    # cohort block to device, run the megastep on it as the
+                    # stacked carry, and scatter the updated member rows back
+                    # into the pool
+                    pool.patch(pt.block, pt.block_cids, pt.gather_seq)
+                    block = jax.tree.map(lambda x: transfer("block", x),
+                                         pt.block)
+                    stacked_state_bytes = max(stacked_state_bytes, sum(
+                        int(x.size) * jnp.dtype(x.dtype).itemsize
+                        for x in jax.tree.leaves(block)))
+                    block, server, tel = tick_fn(block, server, *pt.arrays)
+                    with span("repro.dispatch.sync"):
+                        jax.block_until_ready((block, server))
+                    pool.scatter(pt.block_cids[:pt.block_rows],
+                                 jax.tree.map(np.asarray, block))
                 else:
-                    segments = [kept]
-                for seg in segments:
-                    groups: List[Tuple[int, List]] = []
-                    for tk in seg:
-                        b = bucket_size(len(tk), pad)
-                        if groups and groups[-1][0] == b:
-                            groups[-1][1].append(tk)
-                        else:
-                            groups.append((b, [tk]))
-                    # each same-bucket run is split greedily into exact
-                    # power-of-two chunks (8+2 instead of 16 with 6
-                    # masked ticks): a fully-masked padding tick costs a
-                    # whole bucket's compute, an extra dispatch costs
-                    # microseconds.  Blocks are built only as the queue
-                    # drains: the staging slots rotate over NSLOTS
-                    # buffers, so at most (consumer's current + queued +
-                    # being-built) blocks are in flight.
-                    for _, g in groups:
-                        i = 0
-                        while i < len(g):
-                            n = 1 << ((len(g) - i).bit_length() - 1)
-                            chunk = g[i:i + n]
-                            i += n
-                            pt = builder.build_window(
-                                chunk, t_start=tp, window=W,
-                                sim_time=chunk[-1][-1].time)
-                            tp = pt.t_end
-                            sim_prod = pt.sim_time
-                            if snap is not None:
-                                pt.host_snapshot = snap
-                                snap = None
-                            yield pt
+                    stacked, server, tel = tick_fn(stacked, server, *pt.arrays)
+                    with span("repro.dispatch.sync"):
+                        jax.block_until_ready((stacked, server))
+                with span("repro.telemetry.append"):
+                    telem.append(pt, tel)
+            n_ticks += pt.n_ticks
+            n_windows += 1
+            # steady-state live-set snapshot, off the hot path
+            if n_windows <= 2:
+                peak_live = max(peak_live, _live_device_bytes())
 
-        if not trainable:
-            source = iter(())
-        elif use_prefetch:
-            source = TickPrefetcher(produce(), depth=1)
+        use_prefetch = False
+        resume_t = 0
+        if strategy.schedule == "async":
+            # a client with an empty local split (visible == 0 forever) can
+            # never train: its arrivals are dropped so fabricated zero batches
+            # are never folded in (FedAsync mixes at full weight, without the
+            # n_vis/N guard ASO-Fed has)
+            trainable = {c.cid for c in active if c.stream.n > 0}
+            if resume_from is not None:
+                from repro import checkpoint as ckpt_lib
+
+                stacked, server, host = ckpt_lib.load_run_state(
+                    resume_from, stacked, server, pool=pool)
+                if host.get("strategy") != strategy.name \
+                        or int(host.get("seed", cfg.seed)) != cfg.seed:
+                    raise ValueError(
+                        f"snapshot at {resume_from!r} was written by "
+                        f"strategy={host.get('strategy')!r} "
+                        f"seed={host.get('seed')}; this run is "
+                        f"{strategy.name!r} seed={cfg.seed}")
+                if mesh is not None:
+                    if stacked is not None:
+                        stacked = jax.device_put(stacked, jax.tree.map(
+                            lambda x: sharding_lib.client_sharding(
+                                x.shape, mesh), stacked))
+                    server = jax.device_put(server,
+                                            sharding_lib.replicated(mesh))
+                sched.load_state_dict(host["sched"])
+                for cid_s, st_rng in host["streams"].items():
+                    by_id[int(cid_s)].stream.set_rng_state(st_rng)
+                builder.staleness.load_state_dict(host["staleness"])
+                resume_t = int(host["t"])
+                t = resume_t
+                sim_time = float(host["sim_time"])
+            # adaptive default: the prefetch thread overlaps host batch
+            # building with device execution, which is a pure win on
+            # accelerators and multi-core hosts — but on <4-core CPU boxes
+            # the builder steals cycles from XLA itself and the "overlap" is
+            # negative-sum.  Trajectories are bit-identical either way (the
+            # speculation contract), so the default is free to choose.
+            try:  # affinity respects container/cgroup CPU limits; cpu_count
+                ncpu = len(os.sched_getaffinity(0))  # does not
+            except AttributeError:
+                ncpu = os.cpu_count() or 1
+            use_prefetch = (prefetch if prefetch is not None
+                            else cfg.prefetch if cfg.prefetch is not None
+                            else jax.default_backend() != "cpu" or ncpu >= 4)
+
+            def produce():
+                """Pop + filter + build each window (worker thread when
+                prefetching).  Mirrors the consuming loop's termination logic
+                exactly, so at most the single in-flight speculative peek is
+                ever un-committed.  ``total_limit`` caps *popped* arrivals at
+                the remaining iteration budget — for W == 1 this is exactly
+                the old per-tick ``peek_tick(min(pad, T - tp))`` stream.
+
+                A window is split into maximal runs of *same-bucket* ticks
+                (one fused ``lax.scan`` block per run): a tick must execute
+                at exactly the shape bucket it would ride at W == 1, because
+                XLA's lowering is shape-dependent and inflating a small tick
+                to a larger bucket would break the window-on/off bitwise
+                replay.  In the steady state arrivals-per-tick is stable, so
+                runs span whole windows; bucket switches (the first
+                full-cohort tick, the drained tail, churn) cost one extra
+                dispatch each — never a wrong bit.  With ``cfg.eval_align``
+                windows are first split at ``eval_every`` fold boundaries
+                (``repro.sim.telemetry.split_at_evals``), so the consuming
+                loop's eval check fires at exactly the ticks a window=1 run
+                would evaluate after — a dispatch-count trade, still never a
+                wrong bit.
+                """
+                tp = resume_t
+                sim_prod = float(sim_time)
+                # the iteration budget advances per *fold*: charge it only
+                # for trainable arrivals, so every in-window tick limit
+                # equals the one a window=1 producer would compute (dropped
+                # empty-split clients must not perturb tick membership)
+                kept_count = lambda tk: sum(  # noqa: E731
+                    a.cid in trainable for a in tk)
+                while tp < cfg.T:
+                    snap = None
+                    if checkpoint_path is not None:
+                        # full host cursor, captured at the only clean point:
+                        # the previous window is committed (no speculation in
+                        # flight) and no stream rng draw for the upcoming
+                        # window has been consumed.  It rides the window's
+                        # first PreparedTick to the consumer, which persists
+                        # it together with the device state *before*
+                        # dispatching that tick.
+                        snap = {
+                            "t": tp, "sim_time": sim_prod,
+                            "strategy": strategy.name, "seed": cfg.seed,
+                            "state_residency": cfg.state_residency,
+                            "sched": sched.state_dict(),
+                            "streams": {str(c.cid): c.stream.rng_state()
+                                        for c in active},
+                            "staleness": builder.staleness.state_dict(),
+                        }
+                    with span("repro.sched.peek_window"):
+                        ticks = sched.peek_window(W, pad,
+                                                  total_limit=cfg.T - tp,
+                                                  count=kept_count)
+                        sched.commit()
+                    if not ticks:
+                        break  # drained or over the simulated-time budget
+                    kept = [[a for a in tk if a.cid in trainable]
+                            for tk in ticks]
+                    kept = [tk for tk in kept if tk]
+                    if not kept:
+                        continue  # window held only empty-split clients
+                    if cfg.eval_align and W > 1 and cfg.eval_every > 0:
+                        segments = split_at_evals(kept, tp, cfg.eval_every,
+                                                  count=kept_count)
+                    else:
+                        segments = [kept]
+                    for seg in segments:
+                        groups: List[Tuple[int, List]] = []
+                        for tk in seg:
+                            b = bucket_size(len(tk), pad)
+                            if groups and groups[-1][0] == b:
+                                groups[-1][1].append(tk)
+                            else:
+                                groups.append((b, [tk]))
+                        # each same-bucket run is split greedily into exact
+                        # power-of-two chunks (8+2 instead of 16 with 6
+                        # masked ticks): a fully-masked padding tick costs a
+                        # whole bucket's compute, an extra dispatch costs
+                        # microseconds.  Blocks are built only as the queue
+                        # drains: the staging slots rotate over NSLOTS
+                        # buffers, so at most (consumer's current + queued +
+                        # being-built) blocks are in flight.
+                        for _, g in groups:
+                            i = 0
+                            while i < len(g):
+                                n = 1 << ((len(g) - i).bit_length() - 1)
+                                chunk = g[i:i + n]
+                                i += n
+                                pt = builder.build_window(
+                                    chunk, t_start=tp, window=W,
+                                    sim_time=chunk[-1][-1].time)
+                                tp = pt.t_end
+                                sim_prod = pt.sim_time
+                                if snap is not None:
+                                    pt.host_snapshot = snap
+                                    snap = None
+                                yield pt
+
+            if not trainable:
+                source = iter(())
+            elif use_prefetch:
+                source = TickPrefetcher(produce(), depth=1)
+            else:
+                source = produce()
+            next_eval = (resume_t // cfg.eval_every + 1) * cfg.eval_every \
+                if cfg.eval_every > 0 else cfg.T + 1
+            ckpt_every = int(checkpoint_every) if checkpoint_every \
+                else (cfg.eval_every or cfg.T)
+            next_ckpt = resume_t + ckpt_every if checkpoint_path is not None \
+                else None
+            setup.close()
+            try:
+                while True:
+                    with span("repro.window.wait", phases, "window_wait_s"):
+                        pt = next(source, None)
+                    if pt is None:
+                        break
+                    if (next_ckpt is not None and pt.host_snapshot is not None
+                            and pt.host_snapshot["t"] >= next_ckpt):
+                        # write-before-dispatch: the device state on disk is
+                        # exactly the state the host cursor says it is (the
+                        # snapshot's t counts the folds already applied)
+                        from repro import checkpoint as ckpt_lib
+
+                        # under host residency the pool is the client-state
+                        # payload: at this point every earlier window has
+                        # scattered back (dispatch is synchronous on this
+                        # thread), so the pool holds exactly the state after
+                        # the snapshot's t folds
+                        with span("repro.checkpoint.save"):
+                            ckpt_lib.save_run_state(checkpoint_path, stacked,
+                                                    server, pt.host_snapshot,
+                                                    pool=pool)
+                        next_ckpt = pt.host_snapshot["t"] + ckpt_every
+                    dispatch(pt)
+                    t = pt.t_end
+                    sim_time = pt.sim_time
+                    if trace is not None:
+                        trace.append(
+                            (t, jax.tree.map(np.asarray, eval_params())))
+                    if t >= next_eval or t >= cfg.T:
+                        record(t, sim_time)
+                        while next_eval <= t:
+                            next_eval += cfg.eval_every
+            finally:
+                if isinstance(source, TickPrefetcher):
+                    source.close()
         else:
-            source = produce()
-        next_eval = (resume_t // cfg.eval_every + 1) * cfg.eval_every \
-            if cfg.eval_every > 0 else cfg.T + 1
-        ckpt_every = int(checkpoint_every) if checkpoint_every \
-            else (cfg.eval_every or cfg.T)
-        next_ckpt = resume_t + ckpt_every if checkpoint_path is not None \
-            else None
-        try:
-            for pt in source:
-                if (next_ckpt is not None and pt.host_snapshot is not None
-                        and pt.host_snapshot["t"] >= next_ckpt):
-                    # write-before-dispatch: the device state on disk is
-                    # exactly the state the host cursor says it is (the
-                    # snapshot's t counts the folds already applied)
-                    from repro import checkpoint as ckpt_lib
-
-                    # under host residency the pool is the client-state
-                    # payload: at this point every earlier window has
-                    # scattered back (dispatch is synchronous on this
-                    # thread), so the pool holds exactly the state after
-                    # the snapshot's t folds
-                    ckpt_lib.save_run_state(checkpoint_path, stacked,
-                                            server, pt.host_snapshot,
-                                            pool=pool)
-                    next_ckpt = pt.host_snapshot["t"] + ckpt_every
+            setup.close()
+            for t in range(1, cfg.T + 1):
+                if (strategy.schedule == "sync" and cfg.sim_time_budget
+                        and sim_time > cfg.sim_time_budget):
+                    break
+                arrivals, round_time = sched.next_round(now=sim_time)
+                if not arrivals:
+                    if strategy.schedule == "sync":
+                        if not np.isfinite(round_time):
+                            break  # fleet retired: no trace ever rejoins
+                        # every participant skipped (round_time 0), or the
+                        # whole fleet is off-window: the barrier still waits
+                        # out the gap to the earliest rejoin edge
+                        sim_time += round_time
+                    continue
+                pooled = (strategy.pooled_batches(clients, t, cfg)
+                          if strategy.pooled else None)
+                if strategy.pooled:
+                    arrivals = arrivals[:1]
+                # advance=False: a sync/sweep round's telemetry stamp is the
+                # round index t itself, matching the eval history points
+                pt = builder.build(arrivals, [t] * len(arrivals), sim_time,
+                                   pooled_batch=pooled, advance=False)
                 dispatch(pt)
-                t = pt.t_end
-                sim_time = pt.sim_time
+                n_uploads += len(arrivals)
+                sim_time = sim_time + round_time \
+                    if strategy.schedule == "sync" else float(t)
                 if trace is not None:
                     trace.append((t, jax.tree.map(np.asarray, eval_params())))
-                if t >= next_eval or t >= cfg.T:
+                if (cfg.eval_every > 0 and t % cfg.eval_every == 0) \
+                        or t == cfg.T:
                     record(t, sim_time)
-                    while next_eval <= t:
-                        next_eval += cfg.eval_every
-        finally:
-            if isinstance(source, TickPrefetcher):
-                source.close()
-    else:
-        for t in range(1, cfg.T + 1):
-            if (strategy.schedule == "sync" and cfg.sim_time_budget
-                    and sim_time > cfg.sim_time_budget):
-                break
-            arrivals, round_time = sched.next_round(now=sim_time)
-            if not arrivals:
-                if strategy.schedule == "sync":
-                    if not np.isfinite(round_time):
-                        break  # fleet retired: no trace ever rejoins
-                    # every participant skipped (round_time 0), or the
-                    # whole fleet is off-window: the barrier still waits
-                    # out the gap to the earliest rejoin edge
-                    sim_time += round_time
-                continue
-            pooled = (strategy.pooled_batches(clients, t, cfg)
-                      if strategy.pooled else None)
-            if strategy.pooled:
-                arrivals = arrivals[:1]
-            # advance=False: a sync/sweep round's telemetry stamp is the
-            # round index t itself, matching the eval history points
-            pt = builder.build(arrivals, [t] * len(arrivals), sim_time,
-                               pooled_batch=pooled, advance=False)
-            dispatch(pt)
-            n_uploads += len(arrivals)
-            sim_time = sim_time + round_time if strategy.schedule == "sync" \
-                else float(t)
-            if trace is not None:
-                trace.append((t, jax.tree.map(np.asarray, eval_params())))
-            if (cfg.eval_every > 0 and t % cfg.eval_every == 0) \
-                    or t == cfg.T:
-                record(t, sim_time)
 
-    e0 = time.perf_counter()
-    for (te, ste, we, preds) in pending_evals:
-        history.append(HistoryPoint(te, ste, we, evaluator.metrics_from(preds)))
-    eval_s += time.perf_counter() - e0
-    telem.finalize()
-    peak_live = max(peak_live, _live_device_bytes())
-    if stats is not None:
-        stats.update(
-            ticks=n_ticks, windows=n_windows, iters=t, sim_time=sim_time,
-            host_build_s=round(builder.host_build_s, 6),
-            device_s=round(device_s, 6), eval_s=round(eval_s, 6),
-            prefetch=bool(use_prefetch),
-            devices=int(mesh.devices.size) if mesh is not None else 1,
-            window=W if strategy.schedule == "async" else 1,
-            # "fp32" whenever no codec ran: a codec-less strategy stores
-            # full-precision state regardless of what the config asked for
-            state_dtype=str(cfg.state_dtype) if codec is not None else "fp32",
-            state_residency="host" if pool is not None else "device",
-            stacked_state_bytes=int(stacked_state_bytes),
-            peak_live_device_bytes=int(peak_live),
-            # out-of-core accounting: host-pool footprint and the
-            # gather/patch/scatter traffic (all zero under device
-            # residency — the stack never moves)
-            host_pool_bytes=int(pool.nbytes) if pool is not None else 0,
-            gathered_rows=int(pool.gathered_rows) if pool is not None else 0,
-            scattered_rows=int(pool.scattered_rows) if pool is not None
-            else 0,
-            gather_s=round(pool.gather_s, 6) if pool is not None else 0.0,
-            scatter_s=round(pool.scatter_s, 6) if pool is not None else 0.0,
-            # churn observability: per-arrival staleness (iterations since
-            # the client's previous fold) and the fleet's mean on-fraction
-            # over the simulated horizon, plus the scheduler's deferral /
-            # retirement counters (always-on runs report 1.0 / 0 / 0)
-            staleness_mean=round(builder.staleness.mean, 4),
-            staleness_max=int(builder.staleness.max),
-            availability_utilization=round(
-                availability_utilization(active, sim_time), 4),
-            deferred_arrivals=int(getattr(sched, "deferred", 0)),
-            retired_clients=int(getattr(sched, "retired", 0)),
-            # chaos accounting: the scheduler's deterministic fault
-            # counters (all 0 for fault-free configs)
-            lost_uploads=int(getattr(sched, "lost", 0)),
-            retried_uploads=int(getattr(sched, "retried", 0)),
-            crashed_clients=int(getattr(sched, "crashed", 0)),
-            duplicated_arrivals=int(getattr(sched, "duplicated", 0)),
-            corrupted_arrivals=int(getattr(sched, "corrupted", 0)),
-            # resource accounting: simulated wire bytes of one arrival's
-            # encoded upload, and the run's total over every folded
-            # arrival (async iterations each fold exactly one upload)
-            upload_codec=ucodec.name,
-            upload_bytes=float(upload_bytes),
-            upload_bytes_total=float(upload_bytes) * (
-                t if strategy.schedule == "async" else n_uploads),
-        )
-        if resume_from is not None:
-            stats["resumed_from_t"] = resume_t
-        for k, v in telem.summary().items():
-            stats[k] = round(v, 6) if isinstance(v, float) else v
-        if chaos_tick:
-            # the in-scan admission counters, totalled over the run
-            stats["rejected_uploads"] = int(round(sum(
-                r.values.get("rejected_per_tick", 0.0)
-                for r in telem.records)))
-            stats["clipped_uploads"] = int(round(sum(
-                r.values.get("clipped_per_tick", 0.0)
-                for r in telem.records)))
-        if hasattr(tick_fn, "_cache_size"):
-            stats["tick_cache_size"] = int(tick_fn._cache_size())
+        for (te, ste, we, preds) in pending_evals:
+            with span("repro.eval.extract", phases, "eval_s"):
+                history.append(HistoryPoint(te, ste, we,
+                                            evaluator.metrics_from(preds)))
+        with span("repro.telemetry.finalize"):
+            telem.finalize()
+        with span("repro.builder.close"):
+            builder.close()
+        peak_live = max(peak_live, _live_device_bytes())
+        if stats is not None:
+            stats.update(
+                ticks=n_ticks, windows=n_windows, iters=t, sim_time=sim_time,
+                host_build_s=round(builder.host_build_s, 6),
+                device_s=round(phases.device_s, 6),
+                eval_s=round(phases.eval_s, 6),
+                window_wait_s=round(phases.window_wait_s, 6),
+                run_setup_s=round(phases.run_setup_s, 6),
+                gc_full_collections=full_collections.count,
+                prefetch=bool(use_prefetch),
+                devices=int(mesh.devices.size) if mesh is not None else 1,
+                window=W if strategy.schedule == "async" else 1,
+                # "fp32" whenever no codec ran: a codec-less strategy stores
+                # full-precision state regardless of what the config asked for
+                state_dtype=(str(cfg.state_dtype) if codec is not None
+                             else "fp32"),
+                state_residency="host" if pool is not None else "device",
+                stacked_state_bytes=int(stacked_state_bytes),
+                peak_live_device_bytes=int(peak_live),
+                # out-of-core accounting: host-pool footprint and the
+                # gather/patch/scatter traffic (all zero under device
+                # residency — the stack never moves)
+                host_pool_bytes=int(pool.nbytes) if pool is not None else 0,
+                gathered_rows=(int(pool.gathered_rows) if pool is not None
+                               else 0),
+                scattered_rows=int(pool.scattered_rows) if pool is not None
+                else 0,
+                gather_s=round(pool.gather_s, 6) if pool is not None else 0.0,
+                scatter_s=(round(pool.scatter_s, 6) if pool is not None
+                           else 0.0),
+                # churn observability: per-arrival staleness (iterations since
+                # the client's previous fold) and the fleet's mean on-fraction
+                # over the simulated horizon, plus the scheduler's deferral /
+                # retirement counters (always-on runs report 1.0 / 0 / 0)
+                staleness_mean=round(builder.staleness.mean, 4),
+                staleness_max=int(builder.staleness.max),
+                availability_utilization=round(
+                    availability_utilization(active, sim_time), 4),
+                deferred_arrivals=int(getattr(sched, "deferred", 0)),
+                retired_clients=int(getattr(sched, "retired", 0)),
+                # chaos accounting: the scheduler's deterministic fault
+                # counters (all 0 for fault-free configs)
+                lost_uploads=int(getattr(sched, "lost", 0)),
+                retried_uploads=int(getattr(sched, "retried", 0)),
+                crashed_clients=int(getattr(sched, "crashed", 0)),
+                duplicated_arrivals=int(getattr(sched, "duplicated", 0)),
+                corrupted_arrivals=int(getattr(sched, "corrupted", 0)),
+                # resource accounting: simulated wire bytes of one arrival's
+                # encoded upload, and the run's total over every folded
+                # arrival (async iterations each fold exactly one upload)
+                upload_codec=ucodec.name,
+                upload_bytes=float(upload_bytes),
+                upload_bytes_total=float(upload_bytes) * (
+                    t if strategy.schedule == "async" else n_uploads),
+            )
+            if resume_from is not None:
+                stats["resumed_from_t"] = resume_t
+            for k, v in telem.summary().items():
+                stats[k] = round(v, 6) if isinstance(v, float) else v
+            if chaos_tick:
+                # the in-scan admission counters, totalled over the run
+                stats["rejected_uploads"] = int(round(sum(
+                    r.values.get("rejected_per_tick", 0.0)
+                    for r in telem.records)))
+                stats["clipped_uploads"] = int(round(sum(
+                    r.values.get("clipped_per_tick", 0.0)
+                    for r in telem.records)))
+            if hasattr(tick_fn, "_cache_size"):
+                stats["tick_cache_size"] = int(tick_fn._cache_size())
     return history

@@ -33,12 +33,12 @@ from __future__ import annotations
 import dataclasses
 import queue
 import threading
-import time
 from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.sim.scheduler import Arrival
+from repro.sim.telemetry import span
 
 Array = np.ndarray
 
@@ -199,7 +199,8 @@ class TickBuilder:
         # window blocks carry a leading [T_w] time axis: on a mesh their
         # client axis is axis 1, so they need their own sharding rule
         self.window_transfer = window_transfer or transfer
-        self.host_build_s = 0.0  # accumulated host batch-build + transfer time
+        # summed repro.build_window spans: batch build + transfer seconds
+        self.host_build_s = 0.0
         # tracked here because the builder sees every arrival in fold
         # order — on the producer thread when prefetching — so the
         # engine loop stays untouched
@@ -207,6 +208,13 @@ class TickBuilder:
         self._meta: Dict[Tuple, Dict[str, Array]] = {}
         self._data: Dict[Tuple, Tuple[Array, Array]] = {}
         self._slot = 0
+
+    def close(self) -> None:
+        """Drop the staging buffers.  At large buckets they hold hundreds
+        of MB, and releasing them takes tens of milliseconds: the engine
+        does it inside its run, under a span of its own."""
+        self._meta.clear()
+        self._data.clear()
 
     def _meta_slot(self, shape: Tuple[int, ...], slot: int) -> Dict[str, Array]:
         key = (shape, slot)
@@ -267,9 +275,15 @@ class TickBuilder:
         per-client stream rngs advance identically, which the prefetch
         determinism tests pin down.
         """
-        t0 = time.perf_counter()
+        P = 1 if self.pooled else bucket_size(len(arrivals), self.pad)
+        with span("repro.build_window", self, "host_build_s", bucket=P,
+                  n_ticks=1):
+            return self._build(arrivals, times, sim_time, pooled_batch,
+                               P, advance)
+
+    def _build(self, arrivals, times, sim_time, pooled_batch, P: int,
+               advance: bool) -> PreparedTick:
         n_real = len(arrivals)
-        P = 1 if self.pooled else bucket_size(n_real, self.pad)
         slot = self._slot
         self._slot = (slot + 1) % self.NSLOTS
         meta = self._meta_slot((P,), slot)
@@ -321,7 +335,6 @@ class TickBuilder:
             self.transfer("corrupt", meta["corrupt"]),
             self.transfer("stal", meta["stal"]),
         )
-        self.host_build_s += time.perf_counter() - t0
         t_end = (times[-1] + (1 if advance else 0)) if len(times) else 0
         return PreparedTick(
             arrivals=list(arrivals),
@@ -353,9 +366,14 @@ class TickBuilder:
         (scratch-row writes, no server folds): they cost a little compute
         on the drained tail but never a fresh compilation.
         """
-        t0 = time.perf_counter()
-        Tw = bucket_size(len(ticks), window)
         P = bucket_size(max(len(tk) for tk in ticks), self.pad)
+        with span("repro.build_window", self, "host_build_s", bucket=P,
+                  n_ticks=len(ticks)):
+            return self._build_window(ticks, t_start, window, sim_time, P)
+
+    def _build_window(self, ticks, t_start: int, window: int,
+                      sim_time: float, P: int) -> PreparedTick:
+        Tw = bucket_size(len(ticks), window)
         # host residency: assign each distinct client of the window one
         # pool-block row, in first-appearance order (deterministic — the
         # same arrival stream maps to the same rows at any prefetch
@@ -442,7 +460,6 @@ class TickBuilder:
             self.window_transfer("corrupt", meta["corrupt"]),
             self.window_transfer("stal", meta["stal"]),
         )
-        self.host_build_s += time.perf_counter() - t0
         return PreparedTick(
             arrivals=flat, t_start=t_start, t_end=t_run,
             sim_time=sim_time, arrays=arrays, n_ticks=len(ticks),

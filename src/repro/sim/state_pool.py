@@ -30,11 +30,12 @@ discarded ``peek_window`` speculation never leaks into committed stats.
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
+
+from repro.sim.telemetry import span
 
 # Staging slots for gathered blocks: the prefetch pipeline holds at most
 # one window in flight, one queued, one being built — +1 slack.
@@ -217,55 +218,52 @@ class HostStatePool:
         (leaves ``[R, *shape]``, R = len(rows); int4 leaves arrive
         unpacked to int8).  Pure read — safe to run speculatively on the
         producer thread; pair with :meth:`patch` before dispatch."""
-        t0 = time.perf_counter()
-        rows = np.asarray(rows, np.int64)
-        R = len(rows)
-        seq = self._seq
-        block = self._stage_block(R)
-        leaves = jax.tree_util.tree_leaves(block)
-        for li, out in enumerate(leaves):
-            flat = self._read_rows(li, rows)
-            if self._is_packed[li]:
-                flat = unpack_int4(flat, self._elems[li])
-            out[...] = flat.reshape(out.shape)
-        self.gathered_rows += R
-        self.gather_s += time.perf_counter() - t0
+        with span("repro.pool.gather", self, "gather_s"):
+            rows = np.asarray(rows, np.int64)
+            R = len(rows)
+            seq = self._seq
+            block = self._stage_block(R)
+            leaves = jax.tree_util.tree_leaves(block)
+            for li, out in enumerate(leaves):
+                flat = self._read_rows(li, rows)
+                if self._is_packed[li]:
+                    flat = unpack_int4(flat, self._elems[li])
+                out[...] = flat.reshape(out.shape)
+            self.gathered_rows += R
         return block, seq
 
     def patch(self, block, rows: np.ndarray, gather_seq: int) -> int:
         """Re-copy the rows of ``block`` written since ``gather_seq``
         (consumer side, after all prior windows scattered back).
         Returns the number of patched rows."""
-        t0 = time.perf_counter()
-        rows = np.asarray(rows, np.int64)
-        dirty = np.nonzero(self._last_write[rows] > gather_seq)[0]
-        if len(dirty):
-            leaves = jax.tree_util.tree_leaves(block)
-            drows = rows[dirty]
-            for li, out in enumerate(leaves):
-                flat = self._read_rows(li, drows)
-                if self._is_packed[li]:
-                    flat = unpack_int4(flat, self._elems[li])
-                out[dirty] = flat.reshape((len(dirty),) + out.shape[1:])
-        self.gather_s += time.perf_counter() - t0
+        with span("repro.pool.patch", self, "gather_s"):
+            rows = np.asarray(rows, np.int64)
+            dirty = np.nonzero(self._last_write[rows] > gather_seq)[0]
+            if len(dirty):
+                leaves = jax.tree_util.tree_leaves(block)
+                drows = rows[dirty]
+                for li, out in enumerate(leaves):
+                    flat = self._read_rows(li, drows)
+                    if self._is_packed[li]:
+                        flat = unpack_int4(flat, self._elems[li])
+                    out[dirty] = flat.reshape((len(dirty),) + out.shape[1:])
         return int(len(dirty))
 
     def scatter(self, rows: np.ndarray, block) -> None:
         """Write the first ``len(rows)`` rows of ``block`` (the
         megastep's updated cohort carry, leaves ``[R >= len(rows), ...]``)
         back into the pool."""
-        t0 = time.perf_counter()
-        rows = np.asarray(rows, np.int64)
-        self._seq += 1
-        self._last_write[rows] = self._seq  # before data: see module doc
-        leaves = jax.tree_util.tree_leaves(block)
-        for li, leaf in enumerate(leaves):
-            flat = np.asarray(leaf[:len(rows)]).reshape(len(rows), -1)
-            if self._is_packed[li]:
-                flat = pack_int4(flat)
-            self._write_rows(li, rows, flat)
-        self.scattered_rows += len(rows)
-        self.scatter_s += time.perf_counter() - t0
+        with span("repro.pool.scatter", self, "scatter_s"):
+            rows = np.asarray(rows, np.int64)
+            self._seq += 1
+            self._last_write[rows] = self._seq  # before data: see module doc
+            leaves = jax.tree_util.tree_leaves(block)
+            for li, leaf in enumerate(leaves):
+                flat = np.asarray(leaf[:len(rows)]).reshape(len(rows), -1)
+                if self._is_packed[li]:
+                    flat = pack_int4(flat)
+                self._write_rows(li, rows, flat)
+            self.scattered_rows += len(rows)
 
     def _stage_block(self, R: int):
         """A rotating pre-allocated staging block with leaves

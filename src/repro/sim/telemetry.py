@@ -19,6 +19,11 @@ per-tick resolution without giving the fusion back:
   deferred eval extraction), then materialized once into
   :class:`TickRecord` rows.
 
+The host path's own timing lives here too: :func:`span` puts one
+``repro.*`` interval (a run phase, a window's build, wait or dispatch, an
+eval) on the profiler's clock and feeds the same interval to the
+engine's phase-seconds stats, so the trace and the stats read one clock.
+
 Because a tick always executes at its unfused shape bucket, its telemetry
 row is **bit-identical across window sizes** for the fp32 codec — the
 ``window=32`` loss curve is the ``window=1`` loss curve, point for point
@@ -31,13 +36,82 @@ boundaries so evals land exactly where a ``window=1`` run would put them
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+import gc
+import time
+from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
-from repro.sim.prefetch import PreparedTick
+if TYPE_CHECKING:  # prefetch imports this module's spans
+    from repro.sim.prefetch import PreparedTick
 
 Array = np.ndarray
+
+
+# ---------------------------------------------------------------------------
+# Program spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+class span:
+    """A context manager that opens ``jax.profiler.TraceAnnotation(name,
+    **args)`` (a host event on the profiler's clock, on the calling
+    thread, recorded only while a trace is running) and, given an
+    accumulator, adds the same interval's ``perf_counter`` seconds to
+    ``owner.<attr>``.
+
+    The engine's phase seconds (``host_build_s``, ``device_s``,
+    ``eval_s``, ``gather_s``, ``scatter_s``, ...) are sums of their
+    spans, so a stats key and the trace time the same interval.  A span
+    costs about a microsecond with the profiler off: open one per window,
+    eval or run phase, never per arrival or per row."""
+
+    __slots__ = ("_annotation", "_owner", "_attr", "_t0")
+
+    def __init__(self, name: str, owner=None, attr: Optional[str] = None,
+                 **args):
+        self._annotation = jax.profiler.TraceAnnotation(name, **args)
+        self._owner, self._attr = owner, attr
+        self._t0 = 0.0
+
+    def __enter__(self) -> "span":
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._owner is not None:
+            setattr(self._owner, self._attr, getattr(self._owner, self._attr)
+                    + time.perf_counter() - self._t0)
+        self._annotation.__exit__(*exc)
+
+
+class FullCollections:
+    """While entered, every full (generation-2) garbage collection of the
+    process is a ``repro.gc`` span on the thread that ran it, and
+    ``count`` counts them; younger collections return at once."""
+
+    def __init__(self):
+        self.count = 0
+        self._open: Optional[span] = None
+
+    def _callback(self, phase: str, info: dict) -> None:
+        if info["generation"] != 2:
+            return
+        if phase == "start":
+            self._open = span("repro.gc").__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+            self.count += 1
+
+    def __enter__(self) -> "FullCollections":
+        gc.callbacks.append(self._callback)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        gc.callbacks.remove(self._callback)
 
 
 @dataclasses.dataclass(frozen=True)
