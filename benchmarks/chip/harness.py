@@ -48,18 +48,20 @@ def _read(path: Path) -> dict:
 
 
 def load_cell(name: str, config: Optional[str] = None,
-              traffic: Optional[str] = None, chips: int = 1) -> Cell:
+              traffic: Optional[str] = None, chips: int = 1,
+              root: Path = HERE) -> Cell:
     """The cell ``name`` as ``BENCHMARK.json`` lists it; ``config`` and
-    ``traffic`` name the files of a cell it does not list."""
+    ``traffic`` name the files of a cell it does not list, under ``root``'s
+    ``configs/``, ``traffic/`` and ``cells/``."""
     if config is None:
         bench = _read(ROOT / "BENCHMARK.json")
         entry = {w["name"]: w for w in bench["workloads"]}[name]
         config, traffic, chips = (entry["config"], entry["traffic"],
                                   entry["chips"])
     return Cell(name=name, chips=chips,
-                config=_read(HERE / "configs" / f"{config}.json"),
-                traffic=_read(HERE / "traffic" / f"{traffic}.json"),
-                spec=_read(HERE / "cells" / f"{name}.json"))
+                config=_read(root / "configs" / f"{config}.json"),
+                traffic=_read(root / "traffic" / f"{traffic}.json"),
+                spec=_read(root / "cells" / f"{name}.json"))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,8 +92,10 @@ def make_traffic(cell: Cell, seeds: Seeds):
     K = cell.traffic["clients"]
     if cell.traffic["scenario"] != "always_on":
         raise ValueError(f"unknown scenario {cell.traffic['scenario']!r}")
+    fam = reference.family(cell.config["model"])
     datasets = traffic_lib.make_datasets(cell.config["dataset"], K,
-                                         seeds.data)
+                                         seeds.data,
+                                         getattr(fam, "GENERATORS", None))
     offsets = traffic_lib.network_offsets(K, seeds.offsets,
                                           cell.traffic["delay_range"])
     return datasets, offsets
@@ -128,24 +132,6 @@ class CompileClock:
 # ---------------------------------------------------------------------------
 
 
-HEADLINE = {"regression": "mae", "classification": "accuracy",
-            "multilabel": "hamming"}
-
-
-def _report(task: str):
-    """The benchmark's own eval metric of a job's predictions."""
-    def report(preds, targets):
-        p = np.asarray(preds, np.float32)
-        y = np.asarray(targets).reshape(len(p), -1)
-        if task == "regression":
-            return {"mae": float(np.mean(np.abs(p[:, :1] - y)))}
-        if task == "classification":
-            return {"accuracy": float(np.mean(np.argmax(p, -1) == y[:, 0]))}
-        return {"hamming": float(np.mean((p >= 0) != (y > 0.5)))}
-
-    return report
-
-
 @dataclasses.dataclass
 class JobOut:
     history: List[tuple]  # (global iteration, sim time, metrics) per eval
@@ -169,9 +155,9 @@ class Program:
         self._run, self._make_clients = run, make_sim_clients
         self._telemetry = TelemetryLog
         m, tr, sp = cell.config["model"], cell.traffic, cell.spec
-        self.cfg_model = dataclasses.replace(
-            get_arch(m["arch"]), in_features=m["in_features"],
-            out_features=m["out_features"], hidden=m["hidden"])
+        fam = reference.family(m)
+        self.cfg_model = dataclasses.replace(get_arch(m["arch"]),
+                                             **fam.program_overrides(m))
         self.model = build_model(self.cfg_model, LOCAL)
         self.datasets = datasets
         self.profiles = [DeviceProfile(base_delay=o,
@@ -184,18 +170,19 @@ class Program:
         # the eval metric is the benchmark's: a workload of its own, which
         # also hands each eval's predictions to the check
         self._captured: List[np.ndarray] = []
-        report = _report(m["task"])
+        report = fam.report(m["task"])
 
         def capture(preds, targets):
             self._captured.append(np.array(preds, np.float32))
             return report(preds, targets)
 
         wl_name = f"chipbench.{cell.name}.{id(self)}"
+        cm = self.cfg_model
         WORKLOADS.register(wl_name)(lambda: Workload(
             name=wl_name, task=m["task"], arch=m["arch"],
-            in_features=m["in_features"], out_features=m["out_features"],
-            hidden=m["hidden"], data_fn=None, eval_report=capture,
-            headline=HEADLINE[m["task"]]))
+            in_features=cm.in_features, out_features=cm.out_features,
+            hidden=cm.hidden, data_fn=None, eval_report=capture,
+            headline=fam.headline(m["task"])))
         # the traffic's run_config goes to the program as it is
         self.cfg = RunConfig(**tr["run_config"], T=sp["T"],
                              eval_every=sp["eval_every"], seed=seeds.run,

@@ -1,5 +1,7 @@
 """Plain reference of one benchmark job, written from the paper and the
-configuration files alone: it imports nothing of ``repro``.
+configuration files alone: it imports nothing of ``repro``.  The model
+(parameters, forward pass, loss) is the configuration family's module,
+``families/<model.family>.py``; everything here serves every family.
 
 It replays the job's event stream (per-client round delays drawn at pop
 time from one seeded generator), each client's online data stream, the
@@ -18,7 +20,10 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
+import importlib.util
 import math
+import types
+from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -29,22 +34,36 @@ FAULTS = ("frozen_step", "half_batch", "altered_answer")
 
 
 # ---------------------------------------------------------------------------
-# Model: parameter shapes, the seeded init, forward passes, losses
+# Model: the configuration's family module, the seeded init
 # ---------------------------------------------------------------------------
+
+# where ``family`` looks for ``<family>.py``, in order
+FAMILY_DIRS: List[Path] = [Path(__file__).resolve().parent / "families"]
+_FAMILIES: Dict[Path, types.ModuleType] = {}
+
+
+def family(model: dict) -> types.ModuleType:
+    """The module of ``model["family"]``: ``<family>.py`` from the first
+    of ``FAMILY_DIRS`` that holds one, loaded by file once."""
+    name = model["family"]
+    for d in FAMILY_DIRS:
+        path = Path(d) / f"{name}.py"
+        if path.is_file():
+            break
+    else:
+        raise KeyError(f"no family module {name}.py in {FAMILY_DIRS}")
+    if path not in _FAMILIES:
+        spec = importlib.util.spec_from_file_location(
+            f"chipbench_family_{name}", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _FAMILIES[path] = mod
+    return _FAMILIES[path]
 
 
 def param_shapes(model: dict) -> Dict[str, Tuple[Tuple[int, ...], str]]:
     """name -> (shape, init) of the configuration's parameters."""
-    if model["family"] == "lstm":
-        F, H, O = model["in_features"], model["hidden"], model["out_features"]
-        return {"w_x": ((F, 4 * H), "fan_in"), "w_h": ((H, 4 * H), "fan_in"),
-                "b": ((4 * H,), "zeros"), "fc_w": ((H, O), "fan_in"),
-                "fc_b": ((O,), "zeros")}
-    C, O = model["hidden"], model["out_features"]
-    side = model["image_side"] // 2
-    return {"conv1_w": ((3, 3, 1, C), "fan_in"), "conv1_b": ((C,), "zeros"),
-            "conv2_w": ((3, 3, C, C), "fan_in"), "conv2_b": ((C,), "zeros"),
-            "fc_w": ((side * side * C, O), "fan_in"), "fc_b": ((O,), "zeros")}
+    return family(model).param_shapes(model)
 
 
 def init_params(model: dict, seed: int) -> Dict[str, jnp.ndarray]:
@@ -67,51 +86,17 @@ def init_params(model: dict, seed: int) -> Dict[str, jnp.ndarray]:
 
 
 def first_layer(model: dict) -> str:
-    return "w_x" if model["family"] == "lstm" else "conv1_w"
+    """The parameter the ASO-Fed feature pass reweights."""
+    return family(model).first_layer(model)
 
 
 def forward(model: dict, p, x):
-    if model["family"] == "lstm":
-        zx = jnp.einsum("btf,fg->btg", x, p["w_x"]) + p["b"]
-
-        def cell(carry, zt):
-            h, c = carry
-            z = zt + h @ p["w_h"]
-            i, f, g, o = jnp.split(z, 4, axis=-1)
-            c = jax.nn.sigmoid(f) * c + jax.nn.sigmoid(i) * jnp.tanh(g)
-            h = jax.nn.sigmoid(o) * jnp.tanh(c)
-            return (h, c), None
-
-        h0 = jnp.zeros((x.shape[0], p["w_h"].shape[0]), x.dtype)
-        (h, _), _ = jax.lax.scan(cell, (h0, h0), jnp.swapaxes(zx, 0, 1))
-        return h @ p["fc_w"] + p["fc_b"]
-
-    def conv(v, w, b):
-        y = jax.lax.conv_general_dilated(
-            v, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        return jax.nn.relu(y + b)
-
-    v = conv(x, p["conv1_w"], p["conv1_b"])
-    v = conv(v, p["conv2_w"], p["conv2_b"])
-    v = jax.lax.reduce_window(v, -jnp.inf, jax.lax.max, (1, 2, 2, 1),
-                              (1, 2, 2, 1), "VALID")
-    return v.reshape(v.shape[0], -1) @ p["fc_w"] + p["fc_b"]
+    return family(model).forward(model, p, x)
 
 
 def loss(model: dict, p, x, y):
-    """Mean per-sample loss: squared error of the single output
-    (regression), softmax cross-entropy (classification), sigmoid binary
-    cross-entropy per label (multilabel)."""
-    out = forward(model, p, x)
-    task = model["task"]
-    if task == "regression":
-        return jnp.mean(jnp.square(out[:, 0] - y))
-    if task == "classification":
-        logp = jax.nn.log_softmax(out)
-        return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=-1))
-    y = y.astype(out.dtype)
-    return jnp.mean(jnp.maximum(out, 0) - out * y
-                    + jnp.log1p(jnp.exp(-jnp.abs(out))))
+    """Mean per-sample training loss of the configuration's task."""
+    return family(model).loss(model, p, x, y)
 
 
 def feature_pass(w):
@@ -307,30 +292,41 @@ def _bucket(n: int) -> int:
 
 def replay(model: dict, run: dict, datasets, offsets: Sequence[float], *,
            run_seed: int, stream_seed: int, stop: int,
-           dtype=jnp.float32, fault: Optional[str] = None,
-           pred_block: int = 4096) -> Replay:
+           dtype=jnp.float32, fault: Optional[str] = None) -> Replay:
     """Run the job's first ``stop`` global iterations, with each tick's
     cohort-mean train loss, and predict every client's test split with the
-    central model after them."""
+    central model after them.
+
+    A tick takes at most ``run["max_cohort"]`` arrivals (all ``K`` clients
+    when it is unset), as the program's scheduler does.  With
+    ``run["state_residency"] == "host"`` the client states live in host
+    memory and only each tick's rows go to the device and back; otherwise
+    they stay stacked on the device.  Both give the same answers."""
     if fault is not None and fault not in FAULTS:
         raise ValueError(f"unknown fault {fault!r}")
     precision = "highest" if dtype == jnp.float32 else "default"
     with jax.default_matmul_precision(precision):
         return _replay(model, run, datasets, offsets, run_seed, stream_seed,
-                       stop, dtype, fault, pred_block)
+                       stop, dtype, fault)
 
 
 def _replay(model, run, datasets, offsets, run_seed, stream_seed, stop,
-            dtype, fault, pred_block):
+            dtype, fault):
     K = len(datasets)
+    cap = run.get("max_cohort") or K
+    host = run.get("state_residency") == "host"
     B, E = run["batch_size"], run["local_epochs"]
     init_client, local, fold, received_state = make_rules(model, run, fault)
     cast = lambda t: jax.tree.map(lambda v: v.astype(dtype), t)  # noqa: E731
     w0 = cast(init_params(model, run_seed))
     one = init_client(w0)
-    # client states stacked over K + 1 rows; padded tick slots use row K
-    states = jax.tree.map(lambda v: jnp.broadcast_to(v, (K + 1,) + v.shape),
-                          one)
+    # client states over K + 1 rows; padded tick slots use row K
+    if host:
+        states = jax.tree.map(
+            lambda v: np.repeat(np.asarray(v)[None], K + 1, axis=0), one)
+    else:
+        states = jax.tree.map(
+            lambda v: jnp.broadcast_to(v, (K + 1,) + v.shape), one)
     streams = [DataStream(d[0], d[1], stream_seed + i, run["start_frac"],
                           run["growth"]) for i, d in enumerate(datasets)]
     server = {"w": w0}
@@ -340,9 +336,9 @@ def _replay(model, run, datasets, offsets, run_seed, stream_seed, stop,
     events = EventStream(offsets, run_seed, B, E, run["compute_rate"],
                          run["jitter"])
 
-    @jax.jit
-    def tick_fn(states, server, cids, xs, ys, delays, n_vis, ts, mask):
-        cohort = jax.tree.map(lambda v: v[cids], states)
+    def rows(cohort, server, cids, xs, ys, delays, n_vis, ts, mask):
+        """One tick on its clients' state rows: the local rounds vmapped,
+        the folds in arrival order, the states the clients receive."""
         new, ups, losses = jax.vmap(local)(cohort, xs, ys, delays, ts)
 
         def step(sv, inp):
@@ -352,17 +348,31 @@ def _replay(model, run, datasets, offsets, run_seed, stream_seed, stop,
             return sv2, w
 
         server, ws = jax.lax.scan(step, server, (ups, cids, n_vis, ts, mask))
-        new = jax.vmap(received_state)(new, ws, ts)
-        states = jax.tree.map(lambda s, v: s.at[cids].set(v), states, new)
+        return jax.vmap(received_state)(new, ws, ts), server, losses
+
+    def mean_loss(losses, mask):
         m = mask.astype(jnp.float32)
-        return states, server, jnp.sum(losses.astype(jnp.float32) * m) \
-            / jnp.sum(m)
+        return jnp.sum(losses.astype(jnp.float32) * m) / jnp.sum(m)
+
+    @jax.jit
+    def tick_fn(states, server, cids, xs, ys, delays, n_vis, ts, mask):
+        cohort = jax.tree.map(lambda v: v[cids], states)
+        new, server, losses = rows(cohort, server, cids, xs, ys, delays,
+                                   n_vis, ts, mask)
+        states = jax.tree.map(lambda s, v: s.at[cids].set(v), states, new)
+        return states, server, mean_loss(losses, mask)
+
+    @jax.jit
+    def tick_rows_fn(cohort, server, cids, xs, ys, delays, n_vis, ts, mask):
+        new, server, losses = rows(cohort, server, cids, xs, ys, delays,
+                                   n_vis, ts, mask)
+        return new, server, mean_loss(losses, mask)
 
     x_tmpl, y_tmpl = datasets[0][0], datasets[0][1]
     t, sim_time = 0, 0.0
     ticks, tick_loss = [], []
     while t < stop:
-        arr = events.tick(min(K, stop - t))
+        arr = events.tick(min(cap, stop - t))
         P = _bucket(len(arr))
         cids = np.full(P, K, np.int32)
         xs = np.zeros((P, E, B) + x_tmpl.shape[1:], x_tmpl.dtype)
@@ -379,12 +389,23 @@ def _replay(model, run, datasets, offsets, run_seed, stream_seed, stop,
                 xs[i, e], ys[i, e] = s.batch(t + i, B)
         cast_x = xs.astype(dtype) if xs.dtype == np.float32 else xs
         cast_y = ys.astype(dtype) if ys.dtype == np.float32 else ys
-        new_states, new_server, lval = tick_fn(
-            states, server, jnp.asarray(cids), jnp.asarray(cast_x),
-            jnp.asarray(cast_y), jnp.asarray(delays), jnp.asarray(n_vis),
-            jnp.asarray(ts), jnp.asarray(mask))
+        inputs = (jnp.asarray(cids), jnp.asarray(cast_x),
+                  jnp.asarray(cast_y), jnp.asarray(delays),
+                  jnp.asarray(n_vis), jnp.asarray(ts), jnp.asarray(mask))
+        if host:
+            cohort = jax.tree.map(lambda v: v[cids], states)
+            new_rows, new_server, lval = tick_rows_fn(cohort, server, *inputs)
+        else:
+            new_states, new_server, lval = tick_fn(states, server, *inputs)
         if fault != "frozen_step":  # the fault: a step that changes nothing
-            states, server = new_states, new_server
+            if host:
+                # padded slots all write row K, with equal values
+                for s_leaf, n_leaf in zip(jax.tree.leaves(states),
+                                          jax.tree.leaves(new_rows)):
+                    s_leaf[cids] = np.asarray(n_leaf)
+            else:
+                states = new_states
+            server = new_server
         t += len(arr)
         sim_time = arr[-1].time
         ticks.append((t, len(arr)))
@@ -392,7 +413,10 @@ def _replay(model, run, datasets, offsets, run_seed, stream_seed, stop,
     del states
 
     x_test = np.concatenate([d[2] for d in datasets])
-    predict = jax.jit(lambda w, x: forward(model, w, x.astype(dtype)))
+    predict = jax.jit(lambda w, x: forward(
+        model, w, x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating)
+        else x))
+    pred_block = family(model).pred_block(model)
     preds = []
     for s in range(0, len(x_test), pred_block):
         blk = x_test[s: s + pred_block]

@@ -12,6 +12,39 @@ checked job's first eval is compared with the plain reference
 (``reference.py``) once the timed part is over.  The last line of standard
 output is one JSON object; without a TPU, or with fewer chips than the
 cell asks for, the run exits non-zero and prints no result.
+
+A new configuration enters as new files only; no file here is edited:
+
+* ``configs/<config>.json``: ``model`` (the program's ``arch``, the
+  ``family``, the ``task`` and the sizes the family reads) and
+  ``dataset`` (a ``generator`` name and its keyword arguments);
+* ``families/<family>.py``, once per family, importing nothing of
+  ``repro``; ``reference.family`` loads it by file.  It gives
+  ``param_shapes(model)`` (name -> (shape, ``"fan_in"`` or ``"zeros"``),
+  the seeded init in ``reference.init_params`` reads it),
+  ``first_layer(model)`` (the parameter ASO-Fed's feature pass
+  reweights), ``forward(model, p, x)`` (outputs per row),
+  ``loss(model, p, x, y)`` (the mean training loss, a scalar),
+  ``train_flops_per_iter(config, traffic)`` (model FLOPs of one local
+  round, for ``step_mfu_pct``), ``program_overrides(model)`` (the
+  ``ModelConfig`` fields set on the program's ``arch``), ``report(task)``
+  (a function of (predictions, targets) to a dict of eval metrics),
+  ``headline(task)`` (the key of that dict the program leads with),
+  ``pred_block(model)`` (test rows the reference predicts a call), and
+  may give ``GENERATORS`` (name -> generator, beside ``traffic.py``'s);
+* a generator: ``generator(n_clients=, seed=, **dataset)`` returning
+  ``[(x_train, y_train, x_test, y_test)]`` per client, in the family
+  module's ``GENERATORS``;
+* ``traffic/<traffic>.json``: the strategy, clients, network and data
+  stream knobs, and the ``run_config`` handed to the program as it is
+  (``max_cohort`` and ``state_residency`` bind the reference too);
+* ``cells/<cell>.json``: ``T``, ``eval_every`` and the check's limits;
+* the entries in ``BENCHMARK.json``, and ``metrics/<name>.py`` for a new
+  per-layer metric.
+
+The check's comparison (``harness.compare``) is the same for every
+family: the ticks, each tick's train loss and the eval's prediction
+array, whatever its shape.
 """
 from __future__ import annotations
 

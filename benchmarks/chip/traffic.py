@@ -10,10 +10,12 @@ draws (``repro.sim.profiles.make_profiles``) change the tick sizes, and
 with them the work, from seed to seed.
 
 Every generator returns ``[(x_train, y_train, x_test, y_test)] * n_clients``.
+A family module may bring generators of its own in ``GENERATORS``.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Sequence, Tuple
+from typing import (Callable, Dict, List, Mapping, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
@@ -131,19 +133,25 @@ def fmnist_like(n_clients: int = 20, scale: float = 0.1, seed: int = 3
     return out
 
 
-GENERATORS: Dict[str, Callable[..., List[Quad]]] = {
+Generator = Callable[..., List[Quad]]
+
+GENERATORS: Dict[str, Generator] = {
     "airquality_like": airquality_like,
     "extrasensory_multilabel_like": extrasensory_multilabel_like,
     "fmnist_like": fmnist_like,
 }
 
 
-def make_datasets(dataset: dict, n_clients: int, seed: int) -> List[Quad]:
+def make_datasets(dataset: dict, n_clients: int, seed: int,
+                  generators: Optional[Mapping[str, Generator]] = None
+                  ) -> List[Quad]:
     """The configuration's ``dataset`` entry (generator name and its
-    keyword arguments) for ``n_clients`` clients at ``seed``."""
+    keyword arguments) for ``n_clients`` clients at ``seed``;
+    ``generators`` (a family module's ``GENERATORS``) adds to this
+    module's."""
     kw = {k: v for k, v in dataset.items() if k != "generator"}
-    return GENERATORS[dataset["generator"]](n_clients=n_clients, seed=seed,
-                                            **kw)
+    gen = {**GENERATORS, **(generators or {})}[dataset["generator"]]
+    return gen(n_clients=n_clients, seed=seed, **kw)
 
 
 def network_offsets(n: int, seed: int,
